@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import compat  # noqa: F401  (provides lax.axis_size on 0.4.x)
 from repro.core import tacc
 
 Axis = str | Sequence[str]
@@ -59,6 +58,18 @@ def axis_world(axes: Axis) -> int:
     for a in _axes_tuple(axes):
         n *= lax.axis_size(a)
     return n
+
+
+def _native_reduce(reduce, x, *args, **kwargs):
+    """``reduce(x, ...)``: a native XLA reduction (``lax.psum`` or
+    ``lax.psum_scatter``).  XLA:CPU aborts on a sub-f32 float reduction
+    inside a partially-manual shard_map (DESIGN.md §8), so on the ``cpu``
+    platform those reduce in f32 and cast back — the promotion XLA:CPU would
+    apply itself.  Every other platform reduces in ``x``'s own dtype."""
+    if (tacc.get_platform() == "cpu" and x.dtype.itemsize < 4
+            and jnp.issubdtype(x.dtype, jnp.floating)):
+        return reduce(x.astype(jnp.float32), *args, **kwargs).astype(x.dtype)
+    return reduce(x, *args, **kwargs)
 
 
 RING_BACKENDS = ("xla", "pallas")
@@ -371,7 +382,7 @@ def flat_all_reduce(x, axes: Axis, pod_axis: str | None = None, *,
             out = ring_dma.ring_all_reduce(out, a, n_stripes=n_stripes,
                                            wire_quant=wire_quant)
         return out
-    return lax.psum(x, all_axes)
+    return _native_reduce(lax.psum, x, all_axes)
 
 
 @tacc.register("all_gather", "flat", default=True,
@@ -408,7 +419,8 @@ def flat_reduce_scatter(x, axes: Axis, pod_axis: str | None = None, *,
         return jnp.moveaxis(out, 0, dim) if dim != 0 else out
     out = x
     for a in all_axes:
-        out = lax.psum_scatter(out, a, scatter_dimension=dim, tiled=True)
+        out = _native_reduce(lax.psum_scatter, out, a,
+                             scatter_dimension=dim, tiled=True)
     return out
 
 
@@ -425,13 +437,14 @@ def flat_broadcast(x, axes: Axis, pod_axis: str | None = None, *, root: int = 0)
     all_axes = _axes_tuple(axes) + ((pod_axis,) if pod_axis else ())
     # emulate: zero non-root contributions, then sum.
     flat_idx = _flat_rank_index(all_axes)
-    return lax.psum(jnp.where(flat_idx == root, x, jnp.zeros_like(x)), all_axes)
+    return _native_reduce(
+        lax.psum, jnp.where(flat_idx == root, x, jnp.zeros_like(x)), all_axes)
 
 
 @tacc.register("reduce", "flat", default=True)
 def flat_reduce(x, axes: Axis, pod_axis: str | None = None, *, root: int = 0):
     all_axes = _axes_tuple(axes) + ((pod_axis,) if pod_axis else ())
-    s = lax.psum(x, all_axes)
+    s = _native_reduce(lax.psum, x, all_axes)
     flat_idx = _flat_rank_index(all_axes)
     return jnp.where(flat_idx == root, s, jnp.zeros_like(s))
 
@@ -474,7 +487,7 @@ def hier_all_reduce(x, axes: Axis, pod_axis: str | None = "pod", *,
     """
     local = _axes_tuple(axes)
     if not pod_axis:
-        return lax.psum(x, local)
+        return _native_reduce(lax.psum, x, local)
     cross_rs, cross_ag = resolve_ring_backend(backend, n_stripes=n_stripes,
                                               wire_quant=wire_quant)
     if wire_quant is not None and backend == "pallas":
@@ -487,8 +500,8 @@ def hier_all_reduce(x, axes: Axis, pod_axis: str | None = "pod", *,
     flat, pad = _flatten_pad(x, D * P)
     n = flat.shape[0]
     if D > 1:
-        shard = lax.psum_scatter(flat.reshape(D, n // D), local,
-                                 scatter_dimension=0, tiled=False)
+        shard = _native_reduce(lax.psum_scatter, flat.reshape(D, n // D),
+                               local, scatter_dimension=0, tiled=False)
     else:
         shard = flat
     if cross_dtype is not None and cross_dtype != dtype:
@@ -660,7 +673,7 @@ def pipelined_all_reduce(x, axes: Axis, pod_axis: str | None = "pod", *,
     """
     local = _axes_tuple(axes)
     if not pod_axis:
-        return lax.psum(x, local) if local else x
+        return _native_reduce(lax.psum, x, local) if local else x
     D = 1
     for a in local:
         D *= lax.axis_size(a)
@@ -680,8 +693,9 @@ def pipelined_all_reduce(x, axes: Axis, pod_axis: str | None = "pod", *,
     def local_rs(c):
         if D == 1:
             return c
-        return lax.psum_scatter(c.reshape(D, c.shape[0] // D), local,
-                                scatter_dimension=0, tiled=False)
+        return _native_reduce(lax.psum_scatter,
+                              c.reshape(D, c.shape[0] // D), local,
+                              scatter_dimension=0, tiled=False)
 
     def cross(c):
         if cross_dtype is not None and cross_dtype != dtype:

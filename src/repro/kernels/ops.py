@@ -10,11 +10,13 @@ Wrappers own layout adaptation + padding to MXU-aligned blocks.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.core import tacc
+from repro.core import compat, tacc
 from repro.kernels import ref
 from repro.kernels.collective_reduce import collective_reduce as _cr_pallas
 from repro.kernels.flash_attention import flash_attention_fwd
@@ -40,8 +42,9 @@ def flash_attention(q, k, v, *, kind="causal", window=0, q_offset=0,
                     interpret=False, bq=128, bk=128):
     """Model-layout wrapper for the Pallas flash kernel.
 
-    Decode (Sq < bq) and offset cases fall back to the chunked-jnp path —
-    the kernel targets the big training/prefill shapes.
+    Decode (Sq < 8) and offset cases fall back to the chunked-jnp path —
+    the kernel targets the big training/prefill shapes.  Differentiable:
+    see :func:`_flash` for the backward.
     """
     from repro.models.attention import chunked_attention
     B, Sq, Hq, d = q.shape
@@ -49,19 +52,63 @@ def flash_attention(q, k, v, *, kind="causal", window=0, q_offset=0,
         return chunked_attention(q, k, v, kind=kind, window=window,
                                  q_offset=q_offset, k_offset=k_offset,
                                  k_len=k_len, chunk=chunk or 512, scale=scale)
-    qt = jnp.moveaxis(q, 1, 2)
-    kt = jnp.moveaxis(k, 1, 2)
-    vt = jnp.moveaxis(v, 1, 2)
-    qt, pq = _pad_to(qt, bq, 2)
-    kt, pk = _pad_to(kt, bk, 2)
-    vt, _ = _pad_to(vt, bk, 2)
-    eff_k_len = k.shape[1] if k_len is None else k_len
-    out = flash_attention_fwd(qt, kt, vt, kind=kind, window=window,
-                              k_len=eff_k_len, scale=scale, bq=bq, bk=bk,
-                              interpret=interpret)
-    if pq:
-        out = out[:, :, :Sq]
-    return jnp.moveaxis(out, 1, 2)
+    cfg = (kind, window, k_len, chunk or 512, scale, bq, bk, interpret)
+    return _flash(q, k, v, cfg)
+
+
+def _flash_manual(q, k, v, cfg):
+    """The Pallas forward, in a region where every mesh axis is manual
+    (Mosaic kernels cannot be auto-partitioned): heads split over the auto
+    axes when both head counts divide, otherwise every auto-axis rank runs
+    the whole call."""
+    kind, window, k_len, _, scale, bq, bk, interpret = cfg
+    Sq = q.shape[1]
+
+    def fwd(q, k, v):
+        qt = jnp.moveaxis(q, 1, 2)
+        kt = jnp.moveaxis(k, 1, 2)
+        vt = jnp.moveaxis(v, 1, 2)
+        qt, pq = _pad_to(qt, bq, 2)
+        kt, _ = _pad_to(kt, bk, 2)
+        vt, _ = _pad_to(vt, bk, 2)
+        out = flash_attention_fwd(
+            qt, kt, vt, kind=kind, window=window,
+            k_len=k.shape[1] if k_len is None else k_len, scale=scale,
+            bq=bq, bk=bk, interpret=interpret)
+        if pq:
+            out = out[:, :, :Sq]
+        return jnp.moveaxis(out, 1, 2)
+
+    auto = compat.auto_axes()
+    n_auto = math.prod(auto.values())
+    spec = P()
+    if n_auto > 1 and q.shape[2] % n_auto == 0 and k.shape[2] % n_auto == 0:
+        spec = P(None, None, tuple(auto), None)
+    return compat.manual_region(fwd, in_specs=spec, out_specs=spec)(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash(q, k, v, cfg):
+    """Flash attention with a custom VJP: the forward is the Pallas kernel;
+    the backward is the VJP of ``chunked_attention`` (the same online-softmax
+    math in jnp, recomputed from q, k, v) — not a Pallas backward kernel."""
+    return _flash_manual(q, k, v, cfg)
+
+
+def _flash_fwd(q, k, v, cfg):
+    return _flash_manual(q, k, v, cfg), (q, k, v)
+
+
+def _flash_bwd(cfg, res, g):
+    from repro.models.attention import chunked_attention
+    kind, window, k_len, chunk, scale = cfg[:5]
+    _, vjp = jax.vjp(functools.partial(
+        chunked_attention, kind=kind, window=window, k_len=k_len,
+        chunk=chunk, scale=scale), *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 tacc.register("attention", "tpu")(flash_attention)
@@ -92,11 +139,11 @@ def expert_ffn_pallas(buf, w1, w3, w2, *, interpret=False):
     return grouped_matmul(h, w2, interpret=interpret)
 
 
-tacc.register("expert_ffn", "tpu")(expert_ffn_pallas)
+tacc.register("expert_ffn", "tpu")(compat.manual_region(expert_ffn_pallas))
 tacc.register("expert_ffn", "interpret")(
     functools.partial(expert_ffn_pallas, interpret=True))
 tacc.register("grouped_matmul", "cpu", default=True)(ref.grouped_matmul)
-tacc.register("grouped_matmul", "tpu")(grouped_matmul)
+tacc.register("grouped_matmul", "tpu")(compat.manual_region(grouped_matmul))
 
 
 # ---------------------------------------------------------------------------
@@ -108,7 +155,7 @@ def ssd_scan(x, dt, a_cum, B_in, C_in, *, interpret=False):
 
 
 tacc.register("ssd_scan_kernel", "cpu", default=True)(ref.ssd_scan)
-tacc.register("ssd_scan_kernel", "tpu")(ssd_scan)
+tacc.register("ssd_scan_kernel", "tpu")(compat.manual_region(ssd_scan))
 tacc.register("ssd_scan_kernel", "interpret")(
     functools.partial(ssd_scan, interpret=True))
 
@@ -136,6 +183,7 @@ def collective_reduce(acc, incoming, *, interpret=False):
 
 
 tacc.register("collective_reduce", "cpu", default=True)(ref.collective_reduce)
-tacc.register("collective_reduce", "tpu")(collective_reduce)
+tacc.register("collective_reduce", "tpu")(
+    compat.manual_region(collective_reduce))
 tacc.register("collective_reduce", "interpret")(
     functools.partial(collective_reduce, interpret=True))

@@ -44,7 +44,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import tacc
+from repro.core import compat, tacc
 from repro.kernels.collective_reduce import ragged_block_call
 
 CODECS = ("int8", "fp8")
@@ -193,12 +193,14 @@ def wire_dequant_accum_pallas(acc2: jax.Array, codes2: jax.Array,
 
 
 tacc.register("wire_quantize", "cpu", default=True)(wire_quantize_ref)
-tacc.register("wire_quantize", "tpu")(wire_quantize_pallas)
+tacc.register("wire_quantize", "tpu")(
+    compat.manual_region(wire_quantize_pallas))
 tacc.register("wire_quantize", "interpret")(
     functools.partial(wire_quantize_pallas, interpret=True))
 tacc.register("wire_dequant_accum", "cpu", default=True)(
     wire_dequant_accum_ref)
-tacc.register("wire_dequant_accum", "tpu")(wire_dequant_accum_pallas)
+tacc.register("wire_dequant_accum", "tpu")(
+    compat.manual_region(wire_dequant_accum_pallas))
 tacc.register("wire_dequant_accum", "interpret")(
     functools.partial(wire_dequant_accum_pallas, interpret=True))
 

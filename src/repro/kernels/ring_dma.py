@@ -16,10 +16,11 @@ the step costs ``max(wire, reduce)`` instead of their sum.
 Two execution paths, resolved per TACC platform:
 
   * ``tpu``       -> the fused remote-DMA kernels (``_rs_dma_tpu`` /
-    ``_ag_dma_tpu``): VMEM-resident accumulator, barrier-semaphore neighbor
-    sync, per-(step-parity, stream) DMA semaphores, double-buffered comm
-    slots.  The per-channel payload must fit VMEM — the ``pipelined``
-    collective mode's channel split is the sizing knob.
+    ``_ag_dma_tpu``): HBM-resident payloads streamed through fixed-size
+    VMEM working slots, barrier-semaphore neighbour sync, per-(step,
+    stream, stripe) buffers and DMA semaphores, neighbours named by mesh
+    coordinate.  Any bucket size compiles; each call runs in a region
+    where every mesh axis is manual (``compat.manual_region``).
   * anything else -> the *emulated schedule*: identical numerics and wave
     structure, with the wire hop carried by ``lax.ppermute`` and the
     accumulate dispatched through the TACC ``collective_reduce`` entry (the
@@ -29,7 +30,7 @@ Two execution paths, resolved per TACC platform:
 Orthogonal to both paths, ``n_stripes`` adds the transport layer's
 multi-NIC stripe dimension (DESIGN.md §11): each wire hop is pad-and-sliced
 across k per-link DMA streams — on TPU one ``make_async_remote_copy`` per
-stripe with per-(step-parity, stream, stripe) semaphores, in emulation one
+stripe with per-(step, stream, stripe) semaphores, in emulation one
 ppermute per stripe — bit-equivalent to the unstriped ring by construction.
 
 All functions must run inside a ``jax.shard_map`` whose manual axes include
@@ -46,7 +47,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import tacc
+from repro.core import compat, tacc
 from repro.kernels import quant
 from repro.transport.stripe import MAX_STRIPES
 
@@ -57,7 +58,6 @@ from repro.transport.stripe import MAX_STRIPES
 NUM_BUFFERS = 2
 
 _LANE = 128          # TPU lane width; payloads are reshaped to (rows, _LANE)
-_SUBLANE = 8         # f32 sublane tile; rows padded to NUM_BUFFERS * _SUBLANE
 
 
 def _ring_perm(n: int, direction: int) -> list[tuple[int, int]]:
@@ -246,133 +246,145 @@ def _ag_emulated(x: jax.Array, axis: str, direction: int,
 # ---------------------------------------------------------------------------
 # TPU kernels: fused async-remote-copy rings (not reachable on CPU — the
 # equivalence suite validates the schedule through the emulated path and the
-# collective_reduce kernel body in interpret mode; see DESIGN.md §10).
+# collective_reduce kernel body in interpret mode; the AOT compile suite,
+# tests/test_chip_compile.py, compiles these for a v5e; DESIGN.md §10).
+#
+# Payloads stay in HBM (``memory_space=pl.ANY``): the wire hops are HBM ->
+# remote HBM DMAs, and the accumulate streams each slice through fixed-size
+# VMEM working slots, so VMEM use does not grow with the bucket.  Every ring
+# step has its own send/recv buffers and DMA semaphores, so no slot is ever
+# reused within a call and no backpressure protocol is needed.  Neighbours
+# are named by their coordinate on ``axis`` with ``DeviceIdType.MESH`` (the
+# other mesh coordinates are the sender's own), so on a (pod, data, model)
+# mesh the hop reaches the next island, not a data neighbour.
 # ---------------------------------------------------------------------------
 
-def _rs_dma_kernel(my_ref, x_ref, o_ref, acc_ref, send_buf, recv_buf,
-                   send_sem, recv_sem, cap_sem, *, n, direction, half,
-                   wire_dtype, n_stripes):
-    """Ring reduce-scatter step loop on one device.
+_BLOCK_ROWS = 1024   # VMEM working-slot rows (x _LANE): 512 KiB at f32
+_ROW_ALIGN = 32      # row granularity every wire dtype tiles at (8-bit: 32)
 
-    Protocol (DESIGN.md §10): after a barrier-semaphore handshake with both
-    ring neighbors, step s sends accumulator chunk (my - d·(s+1)) and
-    receives chunk (my - d·(s+2)), each split into NUM_BUFFERS streams with
-    per-(step-parity, stream, stripe) comm slots and DMA semaphores.  Stream
-    0's accumulate runs while stream 1's remote copy is still in flight.
-    Each stream is further sliced into ``n_stripes`` per-link DMA streams
-    (DESIGN.md §11): one ``make_async_remote_copy`` per stripe, each riding
-    its own NIC/ICI lane, all of a stream's stripes started before any wait
-    so the links fill concurrently.
 
-    Backpressure: parity slots alone only tolerate a sender one step ahead,
-    but ring skew is bounded only around the full cycle — so after consuming
-    recv slot ``par`` (all of its stripes) the receiver credits
-    ``cap_sem[par]`` on its upstream sender, and a sender must take that
-    credit before its step s+2 reuses the slot.  Signals are emitted only
-    when a matching wait exists (step s+2 <= n-2) so the regular semaphore
-    drains to zero at kernel exit.
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _slice_layout(L: int, n_slices: int) -> tuple[int, int]:
+    """(rows per slice, block rows) for an L-element payload cut into
+    ``n_slices`` equal row slices, each a whole number of aligned blocks of
+    at most ``_BLOCK_ROWS`` rows (padding stays under one alignment unit per
+    block)."""
+    need = max(_cdiv(L, _LANE * n_slices), 1)
+    n_blk = _cdiv(need, _BLOCK_ROWS)
+    blk = _cdiv(_cdiv(need, n_blk), _ROW_ALIGN) * _ROW_ALIGN
+    return n_blk * blk, blk
+
+
+def _neighbour_barrier(my, n: int, axis: str):
+    """Handshake with both ring neighbours: once it returns, both are inside
+    this kernel, so their buffers are live for remote writes."""
+    barrier = pltpu.get_barrier_semaphore()
+    for nb in (lax.rem(my + 1, n), lax.rem(my - 1 + n, n)):
+        pltpu.semaphore_signal(barrier, 1, device_id={axis: nb},
+                               device_id_type=pltpu.DeviceIdType.MESH)
+    pltpu.semaphore_wait(barrier, 2)
+
+
+def _rs_dma_kernel(my_ref, x_hbm, o_hbm, send_hbm, recv_hbm, a_v, b_v, w_v,
+                   f_v, send_sem, recv_sem, *, n, direction, axis, rows_s,
+                   blk, n_stripes, wire_dtype):
+    """Ring reduce-scatter on one device.  x (n, rows, LANE) f32 -> o (rows,
+    LANE) f32, the reduced chunk ``my``.
+
+    Step s forwards this device's partial of chunk (my - d·(s+1)): its own
+    x chunk plus the partial received at step s-1, cast to the wire dtype,
+    then remote-copied into the downstream neighbour's step-s recv buffer.
+    Each step's payload is split into NUM_BUFFERS streams and each stream
+    into ``n_stripes`` per-link DMAs (DESIGN.md §11): stream 0's accumulate
+    runs while stream 1's previous hop is still on the wire.  The final
+    partial received is chunk ``my``: x[my] + it is the output.
     """
-    rows_s = half // n_stripes
     my = my_ref[0]
     dst = lax.rem(my + direction + n, n)
-    src = lax.rem(my - direction + n, n)
-    barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(barrier, inc=1, device_id=(lax.rem(my + 1, n),),
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
-    pltpu.semaphore_signal(barrier, inc=1, device_id=(lax.rem(my - 1 + n, n),),
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
-    pltpu.semaphore_wait(barrier, 2)
-    acc_ref[...] = x_ref[...]
+    half = n_stripes * rows_s                       # rows per stream
+    _neighbour_barrier(my, n, axis)
+    copies = {}
 
-    def step(s, _):
-        par = lax.rem(s, 2)
-        send_idx = lax.rem(my - direction * (s + 1) + n * (s + 2), n)
-        recv_idx = lax.rem(my - direction * (s + 2) + n * (s + 3), n)
-
-        @pl.when(s >= 2)
-        def _wait_capacity():
-            # dst consumed the step s-2 payload of this parity
-            pltpu.semaphore_wait(cap_sem.at[par], 1)
-
-        for b in range(NUM_BUFFERS):
+    def stream(s, b, idx, out_ref, out_v):
+        """out_ref[b-stream rows] = x[idx] (+ recv[s-1]) through VMEM."""
+        def body(i, carry):
+            lo = pl.multiple_of(b * half + i * blk, _ROW_ALIGN)
+            pltpu.sync_copy(x_hbm.at[idx, pl.ds(lo, blk)], a_v)
+            val = a_v[...]
+            if s > 0:
+                pltpu.sync_copy(recv_hbm.at[s - 1, pl.ds(lo, blk)], b_v)
+                val = val + b_v[...].astype(jnp.float32)
+            out_v[...] = val.astype(out_v.dtype)
+            pltpu.sync_copy(out_v, out_ref.at[pl.ds(lo, blk)])
+            return carry
+        if s > 0:
             for j in range(n_stripes):
-                lo = b * half + j * rows_s
-                send_buf[par, b, j] = \
-                    acc_ref[send_idx, lo:lo + rows_s].astype(wire_dtype)
-        copies = [
-            [pltpu.make_async_remote_copy(
-                src_ref=send_buf.at[par, b, j], dst_ref=recv_buf.at[par, b, j],
-                send_sem=send_sem.at[par, b, j], recv_sem=recv_sem.at[par, b, j],
-                device_id=(dst,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
-             for j in range(n_stripes)]
-            for b in range(NUM_BUFFERS)
-        ]
-        for stream in copies:          # all stripes of all streams launch
-            for c in stream:           # before any wait: every link fills
+                copies[s - 1, b, j].wait_recv()
+        lax.fori_loop(0, half // blk, body, 0)
+
+    for s in range(n - 1):
+        send_idx = lax.rem(my - direction * (s + 1) + n * (s + 2), n)
+        for b in range(NUM_BUFFERS):
+            stream(s, b, send_idx, send_hbm.at[s], w_v)
+            for j in range(n_stripes):
+                rows = pl.ds(b * half + j * rows_s, rows_s)
+                c = pltpu.make_async_remote_copy(
+                    src_ref=send_hbm.at[s, rows], dst_ref=recv_hbm.at[s, rows],
+                    send_sem=send_sem.at[s, b, j],
+                    recv_sem=recv_sem.at[s, b, j],
+                    device_id={axis: dst},
+                    device_id_type=pltpu.DeviceIdType.MESH)
                 c.start()
-        for c in copies[0]:
-            c.wait()
-        # stream 0 reduces while stream 1's DMAs are still on the wire
-        for j in range(n_stripes):
-            lo = j * rows_s
-            acc_ref[recv_idx, lo:lo + rows_s] = (
-                acc_ref[recv_idx, lo:lo + rows_s] +
-                recv_buf[par, 0, j].astype(jnp.float32))
-        for c in copies[1]:
-            c.wait()
-        for j in range(n_stripes):
-            lo = half + j * rows_s
-            acc_ref[recv_idx, lo:lo + rows_s] = (
-                acc_ref[recv_idx, lo:lo + rows_s] +
-                recv_buf[par, 1, j].astype(jnp.float32))
-
-        @pl.when(s + 2 <= n - 2)
-        def _credit_upstream():
-            # recv_buf[par] is drained: upstream may reuse it at step s+2
-            pltpu.semaphore_signal(cap_sem.at[par], inc=1, device_id=(src,),
-                                   device_id_type=pltpu.DeviceIdType.LOGICAL)
-        return ()
-
-    lax.fori_loop(0, n - 1, step, ())
-    o_ref[...] = acc_ref[my]
+                copies[s, b, j] = c
+    for b in range(NUM_BUFFERS):
+        stream(n - 1, b, my, o_hbm, f_v)
+    for c in copies.values():
+        c.wait_send()
 
 
-def _rs_dma_tpu(chunks: jax.Array, axis: str, direction: int,
-                wire_dtype, n_stripes: int = 1) -> jax.Array:
-    """chunks (n, c, ...) -> (c, ...) reduced, f32.  TPU-only fast path."""
+@compat.manual_region
+def _rs_dma_tpu(chunks: jax.Array, my: jax.Array, *, axis: str,
+                direction: int, wire_dtype, n_stripes: int = 1) -> jax.Array:
+    """chunks (n, c, ...) -> (c, ...) reduced, f32.  TPU-only fast path.
+    ``my``: this device's (1,) int32 index on ``axis``, taken outside the
+    manual region (an outer axis's index does not lower inside it)."""
     n = chunks.shape[0]
     rest = chunks.shape[1:]
     L = int(np.prod(rest)) if rest else 1
-    S = _clamp_stripes(n_stripes, -(-L // (NUM_BUFFERS * _SUBLANE * _LANE)))
+    S = _clamp_stripes(n_stripes,
+                       _cdiv(L, NUM_BUFFERS * _ROW_ALIGN * _LANE))
+    rows_s, blk = _slice_layout(L, NUM_BUFFERS * S)
+    rows = NUM_BUFFERS * S * rows_s
     flat = chunks.reshape(n, L).astype(jnp.float32)
-    tile = NUM_BUFFERS * S * _SUBLANE * _LANE
-    pad = (-L) % tile
+    pad = rows * _LANE - L
     if pad:
         flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    rows = flat.shape[1] // _LANE
-    half = rows // NUM_BUFFERS
-    rows_s = half // S
     x = flat.reshape(n, rows, _LANE)
-    my = lax.axis_index(axis).reshape(1).astype(jnp.int32)
     wire = jnp.dtype(wire_dtype)
-    out = pl.pallas_call(
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out, _, _ = pl.pallas_call(
         functools.partial(_rs_dma_kernel, n=n, direction=direction,
-                          half=half, wire_dtype=wire, n_stripes=S),
+                          axis=axis, rows_s=rows_s, blk=blk, n_stripes=S,
+                          wire_dtype=wire),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            in_specs=[hbm],
+            out_specs=[hbm, hbm, hbm],
             scratch_shapes=[
-                pltpu.VMEM((n, rows, _LANE), jnp.float32),      # accumulator
-                pltpu.VMEM((2, NUM_BUFFERS, S, rows_s, _LANE), wire),  # send
-                pltpu.VMEM((2, NUM_BUFFERS, S, rows_s, _LANE), wire),  # recv
-                pltpu.SemaphoreType.DMA((2, NUM_BUFFERS, S)),
-                pltpu.SemaphoreType.DMA((2, NUM_BUFFERS, S)),
-                pltpu.SemaphoreType.REGULAR((2,)),   # per-parity capacity
+                pltpu.VMEM((blk, _LANE), jnp.float32),   # x block
+                pltpu.VMEM((blk, _LANE), wire),          # received block
+                pltpu.VMEM((blk, _LANE), wire),          # outgoing block
+                pltpu.VMEM((blk, _LANE), jnp.float32),   # final block
+                pltpu.SemaphoreType.DMA((n - 1, NUM_BUFFERS, S)),
+                pltpu.SemaphoreType.DMA((n - 1, NUM_BUFFERS, S)),
             ]),
-        out_shape=jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(collective_id=1),
+        out_shape=[jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
+                   jax.ShapeDtypeStruct((n - 1, rows, _LANE), wire),  # send
+                   jax.ShapeDtypeStruct((n - 1, rows, _LANE), wire)],  # recv
+        compiler_params=compat.tpu_compiler_params(collective_id=1),
     )(my, x)
     out = out.reshape(-1)
     if pad:
@@ -380,93 +392,67 @@ def _rs_dma_tpu(chunks: jax.Array, axis: str, direction: int,
     return out.reshape(rest) if rest else out.reshape(())
 
 
-def _ag_dma_kernel(my_ref, x_ref, o_ref, comm, send_sem, recv_sem, cap_sem,
-                   *, n, direction, n_stripes):
-    """Ring all-gather step loop: forward what arrived last step (slot s%2)
-    while the next hop lands in slot (s+1)%2.  Each hop is ``n_stripes``
-    per-link remote copies (DESIGN.md §11), all started before any wait.
-
-    Backpressure mirrors the reduce-scatter kernel: slot ``par`` is fully
-    drained only once step s's sends from it complete (it was copied to the
-    output at step s-1 and is the DMA source at step s), at which point the
-    receiver credits ``cap_sem[par]`` on its upstream sender; a sender takes
-    the credit for slot ``nxt`` before writing it (steps >= 1 — the
-    upstream's very next step reuses the opposite parity).  Signals are
-    emitted only when a matching wait exists so the semaphore drains.
-    """
+def _ag_dma_kernel(my_ref, x_hbm, o_hbm, send_sem, recv_sem, *, n, direction,
+                   axis, rows_s, n_stripes):
+    """Ring all-gather: HBM -> remote HBM, no staging.  Step s forwards
+    chunk (my - d·s) — its own at s=0, else the one that arrived at step
+    s-1 — into the same row of the downstream neighbour's output, as
+    ``n_stripes`` per-link DMAs (DESIGN.md §11).  Every output row is
+    written once per device, so nothing is reused and nothing races."""
     my = my_ref[0]
     dst = lax.rem(my + direction + n, n)
-    src = lax.rem(my - direction + n, n)
-    barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(barrier, inc=1, device_id=(lax.rem(my + 1, n),),
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
-    pltpu.semaphore_signal(barrier, inc=1, device_id=(lax.rem(my - 1 + n, n),),
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
-    pltpu.semaphore_wait(barrier, 2)
-    rows_s = comm.shape[2]
-    comm[0] = x_ref[...].reshape(n_stripes, rows_s, comm.shape[3])
-    o_ref[my] = x_ref[...]
-
-    def step(s, _):
-        par, nxt = lax.rem(s, 2), lax.rem(s + 1, 2)
-
-        @pl.when(s >= 1)
-        def _wait_capacity():
-            # dst drained slot nxt (its step s-1 sends from it completed)
-            pltpu.semaphore_wait(cap_sem.at[nxt], 1)
-
-        copies = [pltpu.make_async_remote_copy(
-            src_ref=comm.at[par, j], dst_ref=comm.at[nxt, j],
-            send_sem=send_sem.at[par, j], recv_sem=recv_sem.at[nxt, j],
-            device_id=(dst,), device_id_type=pltpu.DeviceIdType.LOGICAL)
-            for j in range(n_stripes)]
-        for c in copies:               # every link's stream launches first
+    _neighbour_barrier(my, n, axis)
+    pltpu.sync_copy(x_hbm, o_hbm.at[my])
+    copies = {}
+    for s in range(n - 1):
+        k = lax.rem(my - direction * s + n * (s + 1), n)
+        if s > 0:
+            for j in range(n_stripes):
+                copies[s - 1, j].wait_recv()
+        for j in range(n_stripes):
+            rows = pl.ds(j * rows_s, rows_s)
+            c = pltpu.make_async_remote_copy(
+                src_ref=o_hbm.at[k, rows], dst_ref=o_hbm.at[k, rows],
+                send_sem=send_sem.at[s, j], recv_sem=recv_sem.at[s, j],
+                device_id={axis: dst},
+                device_id_type=pltpu.DeviceIdType.MESH)
             c.start()
-        for c in copies:
-            c.wait()
-
-        @pl.when(s < n - 2)
-        def _credit_upstream():
-            # comm[par] sent and previously copied out: upstream may write it
-            pltpu.semaphore_signal(cap_sem.at[par], inc=1, device_id=(src,),
-                                   device_id_type=pltpu.DeviceIdType.LOGICAL)
-
-        src_idx = lax.rem(my - direction * (s + 1) + n * (s + 2), n)
-        o_ref[src_idx] = comm[nxt].reshape(n_stripes * rows_s, comm.shape[3])
-        return ()
-
-    lax.fori_loop(0, n - 1, step, ())
+            copies[s, j] = c
+    for j in range(n_stripes):
+        copies[n - 2, j].wait_recv()
+    for c in copies.values():
+        c.wait_send()
 
 
-def _ag_dma_tpu(x: jax.Array, axis: str, direction: int,
+@compat.manual_region
+def _ag_dma_tpu(x: jax.Array, my: jax.Array, *, axis: str, direction: int,
                 n_stripes: int = 1) -> jax.Array:
-    """x (c, ...) -> (n, c, ...) rank-stacked.  TPU-only fast path."""
+    """x (c, ...) -> (n, c, ...) rank-stacked.  TPU-only fast path (``my``
+    as in :func:`_rs_dma_tpu`)."""
     n = lax.axis_size(axis)
     shape = x.shape
     L = int(np.prod(shape))
-    S = _clamp_stripes(n_stripes, -(-L // (_SUBLANE * _LANE)))
+    S = _clamp_stripes(n_stripes, _cdiv(L, _ROW_ALIGN * _LANE))
+    rows_s = _cdiv(_cdiv(L, S * _LANE), _ROW_ALIGN) * _ROW_ALIGN
+    rows = S * rows_s
     flat = x.reshape(L)
-    pad = (-L) % (S * _SUBLANE * _LANE)
+    pad = rows * _LANE - L
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    rows = flat.shape[0] // _LANE
-    rows_s = rows // S
-    my = lax.axis_index(axis).reshape(1).astype(jnp.int32)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         functools.partial(_ag_dma_kernel, n=n, direction=direction,
-                          n_stripes=S),
+                          axis=axis, rows_s=rows_s, n_stripes=S),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            in_specs=[hbm],
+            out_specs=hbm,
             scratch_shapes=[
-                pltpu.VMEM((2, S, rows_s, _LANE), x.dtype),
-                pltpu.SemaphoreType.DMA((2, S)),
-                pltpu.SemaphoreType.DMA((2, S)),
-                pltpu.SemaphoreType.REGULAR((2,)),   # per-parity capacity
+                pltpu.SemaphoreType.DMA((n - 1, S)),
+                pltpu.SemaphoreType.DMA((n - 1, S)),
             ]),
         out_shape=jax.ShapeDtypeStruct((n, rows, _LANE), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(collective_id=2),
+        compiler_params=compat.tpu_compiler_params(collective_id=2),
     )(my, flat.reshape(rows, _LANE))
     out = out.reshape(n, -1)
     if pad:
@@ -476,6 +462,10 @@ def _ag_dma_tpu(x: jax.Array, axis: str, direction: int,
 
 def _on_tpu() -> bool:
     return tacc.get_platform() == "tpu"
+
+
+def _axis_pos(axis: str) -> jax.Array:
+    return lax.axis_index(axis).reshape(1).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -518,7 +508,9 @@ def ring_reduce_scatter(x: jax.Array, axis: str, *, direction: int = 1,
         return out.astype(x.dtype)
     wire = jnp.dtype(wire_dtype) if wire_dtype is not None else x.dtype
     if _on_tpu():
-        out = _rs_dma_tpu(chunks, axis, direction, wire, n_stripes)
+        out = _rs_dma_tpu(chunks, _axis_pos(axis), axis=axis,
+                          direction=direction, wire_dtype=wire,
+                          n_stripes=n_stripes)
     else:
         out = _rs_emulated(chunks, axis, direction, wire, n_stripes)
     return out.astype(x.dtype)
@@ -565,9 +557,11 @@ def ring_all_gather(x: jax.Array, axis: str, *, direction: int = 1,
     if wire_quant is not None:
         out = _quant_ag_emulated(x, axis, direction, wire_quant, n_stripes)
         out = out.astype(x.dtype)
+    elif _on_tpu():
+        out = _ag_dma_tpu(x, _axis_pos(axis), axis=axis, direction=direction,
+                          n_stripes=n_stripes)
     else:
-        out = _ag_dma_tpu(x, axis, direction, n_stripes) if _on_tpu() else \
-            _ag_emulated(x, axis, direction, n_stripes)
+        out = _ag_emulated(x, axis, direction, n_stripes)
     return out.reshape((n * x.shape[0],) + x.shape[1:])
 
 
@@ -588,8 +582,10 @@ def ring_all_gather_bidir(x: jax.Array, axis: str, *,
         if wire_quant is not None:
             return _quant_ag_emulated(xs, axis, direction, wire_quant,
                                       n_stripes).astype(x.dtype)
-        return _ag_dma_tpu(xs, axis, direction, n_stripes) if _on_tpu() \
-            else _ag_emulated(xs, axis, direction, n_stripes)
+        if _on_tpu():
+            return _ag_dma_tpu(xs, _axis_pos(axis), axis=axis,
+                               direction=direction, n_stripes=n_stripes)
+        return _ag_emulated(xs, axis, direction, n_stripes)
 
     out = jnp.concatenate([one(x[:h], 1), one(x[h:], -1)], axis=1)
     return out.reshape((n * c,) + x.shape[1:])

@@ -221,8 +221,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, zero: int = 3,
         t_compile = time.time() - t0
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):     # jax 0.4.x: list of one dict
-            ca = ca[0] if ca else {}
         if verbose:
             print(f"  memory_analysis: {ma}")
             print(f"  cost_analysis: flops={ca.get('flops')} "

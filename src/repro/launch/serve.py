@@ -32,9 +32,11 @@ def main():
 
     from repro.configs import get_config
     from repro.core import compat
+    from repro.launch.cache import enable_compile_cache
     from repro.models import build
     from repro.serve.engine import Batcher, Request, make_serve_programs
 
+    enable_compile_cache()
     axes = ("pod", "data", "model")[-len(shape):]
     mesh = compat.make_mesh(shape, axes)
     cfg = get_config(args.arch)

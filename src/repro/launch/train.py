@@ -19,7 +19,9 @@ import argparse
 import os
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default: the command line), train, and return the
+    per-step metric history (``ft.run_supervised``'s list of dicts)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--steps", type=int, default=50)
@@ -83,7 +85,7 @@ def main():
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="append a unified-schema metric line (the fleet "
                          "snapshot) to this JSONL file at the end of the run")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     shape = tuple(int(x) for x in args.mesh_shape.split(","))
     n_dev = 1
@@ -99,12 +101,14 @@ def main():
     from repro.configs.base import RunConfig
     from repro.core.balance import uniform_plan
     from repro.data.pipeline import DataPipeline
+    from repro.launch.cache import enable_compile_cache
     from repro.models import build
     from repro.train import checkpoint as ck
     from repro.train import ft
     from repro.train.trainer import make_train_program
 
     from repro.core import compat
+    enable_compile_cache()
     axes = ("pod", "data", "model")[-len(shape):]
     mesh = compat.make_mesh(shape, axes)
     cfg = get_config(args.arch)
@@ -258,6 +262,7 @@ def main():
         for k, p in paths.items():
             print(f"telemetry {k}: {p}")
     print(f"done: loss {hist[0]['loss']:.4f} -> {hist[-1]['loss']:.4f}")
+    return hist
 
 
 if __name__ == "__main__":

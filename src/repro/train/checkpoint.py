@@ -43,6 +43,16 @@ def _leaf_paths(tree):
     return [(jax.tree_util.keystr(kp), leaf) for kp, leaf in flat]
 
 
+def _storable(arr: np.ndarray) -> np.ndarray:
+    """``np.save`` writes ml_dtypes leaves (bfloat16, float8_*) as raw void
+    that ``np.load`` cannot hand back; store their bytes as a same-width
+    unsigned-integer view instead — the manifest's ``dtype`` views them
+    back on load."""
+    if arr.dtype.kind == "V":
+        return arr.view(np.dtype(f"u{arr.dtype.itemsize}"))
+    return arr
+
+
 def _crc(arr: np.ndarray) -> str:
     """Leaf checksum: md5 over the first MiB (cheap, catches torn writes)."""
     return hashlib.md5(arr.tobytes()[:1 << 20]).hexdigest()
@@ -82,7 +92,7 @@ def save(ckpt_dir: str, step: int, state, *, keep: int = 3,
     for i, (path, leaf) in enumerate(_leaf_paths(state)):
         arr = np.asarray(jax.device_get(leaf))
         fname = f"arr_{i:05d}.npy"
-        np.save(os.path.join(tmp, fname), arr)
+        np.save(os.path.join(tmp, fname), _storable(arr))
         manifest["leaves"].append({
             "path": path, "file": fname, "shape": list(arr.shape),
             "dtype": str(arr.dtype),
@@ -220,6 +230,8 @@ def restore(ckpt_dir: str, step: int, state_like, shardings=None, *,
         if verify and entry.get("crc") and _crc(arr) != entry["crc"]:
             raise CorruptCheckpointError(
                 f"checksum mismatch for {entry['path']} in {d}")
+        if str(arr.dtype) != entry["dtype"]:
+            arr = arr.view(jax.numpy.dtype(entry["dtype"]))
         host.append(arr)
     return place_tree(host, state_like, shardings)
 

@@ -24,6 +24,7 @@ import dataclasses
 import time
 from typing import Callable
 
+import jax
 import numpy as np
 
 from repro.core.balance import HetPlan, PodProfile, make_plan
@@ -176,6 +177,9 @@ def run_supervised(step_fn: Callable, state, batches, *, ckpt_dir: str,
                 injected["done"] = True
                 raise InjectedFailure(f"injected failure at step {step}")
             state, metrics = step_fn(state, batch)
+            # the device finishes after dispatch returns: time the step, not
+            # its enqueue
+            jax.block_until_ready((state, metrics))
             dt = time.perf_counter() - t0
             if monitor is not None and monitor.observe(dt):
                 metrics = {**metrics, "straggler_flag": True}

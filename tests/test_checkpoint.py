@@ -75,6 +75,40 @@ def test_failure_recovery_bit_exact(tmp_path, mesh3):
         assert abs(by_step_fail[s] - by_step_clean[s]) < 1e-5, s
 
 
+def test_bf16_leaf_resume_through_supervisor(tmp_path):
+    """bf16 leaves (the full-size launcher's param dtype) survive save ->
+    restore: stored as a same-width integer view, viewed back from the
+    manifest dtype.  A failure injected mid-run resumes bit-exact."""
+    w0 = jnp.asarray(np.random.RandomState(3).randn(8, 16), jnp.bfloat16)
+
+    @jax.jit
+    def step_fn(state, batch):
+        w = (state["w"].astype(jnp.float32) * 0.9 + batch["x"]).astype(
+            jnp.bfloat16)
+        return ({"w": w, "m": state["m"] + 1.0},
+                {"loss": jnp.mean(w.astype(jnp.float32))})
+
+    def batches(step):
+        return {"x": jnp.full((8, 16), 0.01 * (step + 1), jnp.float32)}
+
+    def run(d, fail_at=None):
+        s0 = {"w": w0, "m": jnp.zeros((3,), jnp.float32)}
+        ck.save(str(d), 0, s0)
+        return ft.run_supervised(step_fn, s0, batches, ckpt_dir=str(d),
+                                 ckpt_every=2, n_steps=6, fail_at=fail_at)
+
+    final, hist = run(tmp_path / "fail", fail_at=3)
+    clean, hist_clean = run(tmp_path / "clean")
+    assert final["w"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(final["w"]),
+                                  np.asarray(clean["w"]))
+    assert [h["loss"] for h in hist][-3:] == [h["loss"] for h in hist_clean][-3:]
+    _, restored = ck.restore_latest(str(tmp_path / "fail"), final)
+    assert restored["w"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(restored["w"]),
+                                  np.asarray(final["w"]))
+
+
 def test_elastic_restore_to_different_mesh(tmp_path, mesh3, mesh2):
     """Checkpoint written on the 3-axis mesh restores onto the 2-axis mesh
     (pod loss -> survivors continue), matching values exactly."""

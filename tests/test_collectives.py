@@ -1,5 +1,7 @@
 """HetCCL collective semantics: every hier op must equal its flat/native
 equivalent, and the differentiable FSDP gather must have the right adjoint."""
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +12,14 @@ from repro.core import collectives as C
 from repro.core import compat, hetccl
 
 rng = np.random.RandomState(0)
+
+
+@pytest.fixture(autouse=True)
+def _seed_inputs(request):
+    """Each test draws its inputs from a seed of its own name, not from
+    whatever the tests before it in this worker left in the shared
+    generator (pytest-xdist orders tests differently from run to run)."""
+    rng.seed(zlib.crc32(request.node.name.encode()))
 
 
 def run(mesh, fn, x, in_spec, out_spec):

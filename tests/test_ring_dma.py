@@ -10,6 +10,8 @@ decompression) — the piece the TPU DMA kernel fuses — is what actually runs.
 """
 import os
 
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,14 @@ from repro.core import collectives as C
 from repro.kernels import ring_dma
 
 rng = np.random.RandomState(7)
+
+
+@pytest.fixture(autouse=True)
+def _seed_inputs(request):
+    """Each test draws its inputs from a seed of its own name, not from
+    whatever the tests before it in this worker left in the shared
+    generator (pytest-xdist orders tests differently from run to run)."""
+    rng.seed(zlib.crc32(request.node.name.encode()))
 
 # CI matrix knobs: the pallas-equivalence job re-runs this whole suite with
 # the transport stripe count forced to 2 (DESIGN.md §11) and again with the

@@ -56,6 +56,42 @@ def test_zero3_convergence_matches_zero1(mesh3):
     np.testing.assert_allclose(z1, z3, rtol=1e-2, atol=1e-2)
 
 
+def test_pallas_attention_step_matches_reference(mesh3):
+    """The train step with the TPU attention entry (Pallas flash forward in
+    a nested all-manual region inside the step's partially-manual
+    shard_map, chunked-jnp backward) trains like the jnp reference: the
+    kernel runs in interpret mode here, on the trainer's own layout."""
+    def losses(platform):
+        tacc.set_platform(platform)
+        try:
+            return _losses(mesh3, "hier", 1, steps=3)
+        finally:
+            tacc.set_platform_auto()
+
+    assert tacc.resolve_variant("attention") == "cpu"
+    np.testing.assert_allclose(losses("interpret"), losses("cpu"),
+                               rtol=1e-4)
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache goes to one fixed, gitignored directory in the checkout."""
+    from pathlib import Path
+    from repro.launch import cache
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = Path(__file__).resolve().parents[1]
+    try:
+        assert cache.enable_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(repo / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+
+
 def test_tacc_table_is_populated():
     """Appendix C analogue: the function table lists all registered ops."""
     t = tacc.table()

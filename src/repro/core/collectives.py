@@ -457,7 +457,24 @@ def p2p(x, axis: str, perm: Sequence[tuple[int, int]]):
 
 # ---------------------------------------------------------------------------
 # Hierarchical (HetCCL) collectives: local native stage + cross-pod ring.
+#
+# Each stage runs under a named scope (DESIGN.md §16), nested in the
+# ``hetccl.*`` scope of the dispatch: ``intra`` for the vendor-local native
+# stage, ``cross`` for the cross-pod ring, so a device trace splits a
+# collective's time between the two.
 # ---------------------------------------------------------------------------
+
+INTRA_SCOPE = "intra"
+CROSS_SCOPE = "cross"
+
+
+def _staged(scope: str, fn):
+    """``fn`` run under the named scope of its stage."""
+    def run(x):
+        with jax.named_scope(scope):
+            return fn(x)
+    return run
+
 
 def _flatten_pad(x: jax.Array, multiple: int) -> tuple[jax.Array, int]:
     flat = x.reshape(-1)
@@ -500,17 +517,21 @@ def hier_all_reduce(x, axes: Axis, pod_axis: str | None = "pod", *,
     flat, pad = _flatten_pad(x, D * P)
     n = flat.shape[0]
     if D > 1:
-        shard = _native_reduce(lax.psum_scatter, flat.reshape(D, n // D),
-                               local, scatter_dimension=0, tiled=False)
+        with jax.named_scope(INTRA_SCOPE):
+            shard = _native_reduce(lax.psum_scatter, flat.reshape(D, n // D),
+                                   local, scatter_dimension=0, tiled=False)
     else:
         shard = flat
-    if cross_dtype is not None and cross_dtype != dtype:
-        shard = shard.astype(cross_dtype)
-    shard = cross_ag(cross_rs(shard, pod_axis), pod_axis)
-    if cross_dtype is not None and cross_dtype != dtype:
-        shard = shard.astype(dtype)
+    with jax.named_scope(CROSS_SCOPE):
+        if cross_dtype is not None and cross_dtype != dtype:
+            shard = shard.astype(cross_dtype)
+        shard = cross_ag(cross_rs(shard, pod_axis), pod_axis)
+        if cross_dtype is not None and cross_dtype != dtype:
+            shard = shard.astype(dtype)
     if D > 1:
-        flat = lax.all_gather(shard, local, axis=0, tiled=False).reshape(n)
+        with jax.named_scope(INTRA_SCOPE):
+            flat = lax.all_gather(shard, local, axis=0,
+                                  tiled=False).reshape(n)
     else:
         flat = shard
     if pad:
@@ -524,15 +545,17 @@ def hier_all_gather(x, axes: Axis, pod_axis: str | None = "pod", *, dim: int = 0
                     tiled: bool = True, backend: str = "xla",
                     n_stripes: int = 1, wire_quant: str | None = None):
     """Local native gather, then cross-pod ring gather (pod-major order)."""
-    out = flat_all_gather(x, axes, None, dim=dim, tiled=tiled)
+    with jax.named_scope(INTRA_SCOPE):
+        out = flat_all_gather(x, axes, None, dim=dim, tiled=tiled)
     if pod_axis:
         _, cross_ag = resolve_ring_backend(backend, n_stripes=n_stripes,
                                            wire_quant=wire_quant)
-        if dim != 0:
-            out = jnp.moveaxis(out, dim, 0)
-        out = cross_ag(out, pod_axis)
-        if dim != 0:
-            out = jnp.moveaxis(out, 0, dim)
+        with jax.named_scope(CROSS_SCOPE):
+            if dim != 0:
+                out = jnp.moveaxis(out, dim, 0)
+            out = cross_ag(out, pod_axis)
+            if dim != 0:
+                out = jnp.moveaxis(out, 0, dim)
     return out
 
 
@@ -546,12 +569,14 @@ def hier_reduce_scatter(x, axes: Axis, pod_axis: str | None = "pod", *,
     if pod_axis:
         cross_rs, _ = resolve_ring_backend(backend, n_stripes=n_stripes,
                                            wire_quant=wire_quant)
-        if dim != 0:
-            out = jnp.moveaxis(out, dim, 0)
-        out = cross_rs(out, pod_axis)
-        if dim != 0:
-            out = jnp.moveaxis(out, 0, dim)
-    return flat_reduce_scatter(out, axes, None, dim=dim)
+        with jax.named_scope(CROSS_SCOPE):
+            if dim != 0:
+                out = jnp.moveaxis(out, dim, 0)
+            out = cross_rs(out, pod_axis)
+            if dim != 0:
+                out = jnp.moveaxis(out, 0, dim)
+    with jax.named_scope(INTRA_SCOPE):
+        return flat_reduce_scatter(out, axes, None, dim=dim)
 
 
 @tacc.register("all_to_all", "hier")
@@ -573,19 +598,23 @@ def hier_all_to_all(x, axes: Axis, pod_axis: str | None = "pod", *,
     n = x.shape[0]
     assert n % (P * D) == 0, (n, P, D)
     blk = x.reshape((P, D, n // (P * D)) + x.shape[1:])
-    blk = ring_all_to_all(blk, pod_axis)             # exchange pod superblocks
+    with jax.named_scope(CROSS_SCOPE):
+        blk = ring_all_to_all(blk, pod_axis)         # exchange pod superblocks
     blk = blk.reshape((P * D, n // (P * D)) + x.shape[1:])
     blk = blk.reshape((P, n // P) + x.shape[1:])
-    out = lax.all_to_all(blk, _axes_tuple(axes), split_axis=1, concat_axis=1,
-                         tiled=True)
+    with jax.named_scope(INTRA_SCOPE):
+        out = lax.all_to_all(blk, _axes_tuple(axes), split_axis=1,
+                             concat_axis=1, tiled=True)
     return out.reshape((n,) + x.shape[1:])
 
 
 @tacc.register("broadcast", "hier")
 def hier_broadcast(x, axes: Axis, pod_axis: str | None = "pod", *, root: int = 0):
-    out = flat_broadcast(x, axes, None, root=root)   # local stage from local root
+    with jax.named_scope(INTRA_SCOPE):       # local stage from local root
+        out = flat_broadcast(x, axes, None, root=root)
     if pod_axis:
-        out = ring_broadcast(out, pod_axis, root=0)
+        with jax.named_scope(CROSS_SCOPE):
+            out = ring_broadcast(out, pod_axis, root=0)
     return out
 
 
@@ -710,7 +739,9 @@ def pipelined_all_reduce(x, axes: Axis, pod_axis: str | None = "pod", *,
             return c
         return lax.all_gather(c, local, axis=0, tiled=False).reshape(-1)
 
-    outs = software_pipeline(chunks, (local_rs, cross, local_ag))
+    outs = software_pipeline(chunks, (_staged(INTRA_SCOPE, local_rs),
+                                      _staged(CROSS_SCOPE, cross),
+                                      _staged(INTRA_SCOPE, local_ag)))
     flat = jnp.concatenate(outs) if C > 1 else outs[0]
     if pad:
         flat = flat[:n - pad]
@@ -749,7 +780,8 @@ def pipelined_all_gather(x, axes: Axis, pod_axis: str | None = "pod", *,
     def cross(c):
         return cross_ring_ag(c, pod_axis)
 
-    outs = software_pipeline(chunks, (local_ag, cross))
+    outs = software_pipeline(chunks, (_staged(INTRA_SCOPE, local_ag),
+                                      _staged(CROSS_SCOPE, cross)))
     if C > 1:
         # chunk j holds [rank0 chunk-j, rank1 chunk-j, ...]; re-interleave to
         # rank-major: (W, cj, ...) stacked along the chunk dim.
@@ -797,7 +829,8 @@ def pipelined_reduce_scatter(x, axes: Axis, pod_axis: str | None = "pod", *,
     def local_rs(c):
         return flat_reduce_scatter(c, axes, None, dim=0)
 
-    outs = software_pipeline(chunks, (cross, local_rs))
+    outs = software_pipeline(chunks, (_staged(CROSS_SCOPE, cross),
+                                      _staged(INTRA_SCOPE, local_rs)))
     out = jnp.concatenate(outs) if C > 1 else outs[0]
     return jnp.moveaxis(out, 0, dim) if dim != 0 else out
 

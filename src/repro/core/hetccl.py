@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from repro.core import tacc
 from repro.core import collectives as _coll  # noqa: F401  (registers impls)
 from repro.comm.communicator import Communicator, from_config, variant_for
-from repro.comm.policy import CommPolicy, PolicyTable
+from repro.comm.policy import CommPolicy, PolicyTable, size_class
 
 _SWAPPABLE_OPS = ("all_reduce", "all_gather", "reduce_scatter", "all_to_all",
                   "broadcast", "reduce")
@@ -305,8 +305,9 @@ def disarm_watchdog() -> None:
 
 
 # Telemetry hook (DESIGN.md §16): an installed repro.obs.Tracer records every
-# eager dispatch as a policy-tagged span.  Same eager-only contract as the
-# watchdog above; same stack-safe install/uninstall shape as the communicator.
+# eager dispatch as a policy-tagged span (a traced one is named instead, see
+# SCOPE).  Same eager-only span contract as the watchdog above; same
+# stack-safe install/uninstall shape as the communicator.
 _TRACER = None
 _TRACER_STACK: list = []
 
@@ -330,21 +331,35 @@ def current_tracer():
     return _TRACER
 
 
+# Name of a traced dispatch in the compiled program (DESIGN.md §16): every op
+# it emits carries ``hetccl.<op>.<size_class>.<variant>`` in its HLO op_name,
+# with the stage scopes of ``collectives`` (intra / cross) nested below.
+SCOPE = "hetccl.{op}.{size_class}.{variant}"
+
+
 def _call(op: str, x, cfg, **kw):
     """Communicator-scoped dispatch (DESIGN.md §12): resolve this payload's
     policy from the active communicator's (op, size class) table, then let
     tacc.dispatch map exactly the policy fields the resolved variant
     declared.  Eager dispatches are observed by an armed watchdog (deadline
     enforcement, DESIGN.md §15) and an installed/pinned tracer (telemetry
-    spans, DESIGN.md §16); traced dispatches inside jit skip both."""
+    spans, DESIGN.md §16); traced dispatches inside jit skip both and are
+    *named* instead: their ops run under the :data:`SCOPE` of this call, so
+    a device trace attributes them to ``(op, size_class, variant)``."""
     c = _as_communicator(cfg)
     nbytes = _payload_bytes(op, x, c)
     pol = c.policy(op, nbytes)
     variant = c.variant_for(op, pol)
     if variant == "pipelined" and c.pipeline_chunk_bytes:
         kw.setdefault("pipeline_chunk_bytes", c.pipeline_chunk_bytes)
+    if isinstance(x, jax.core.Tracer):
+        name = SCOPE.format(op=op, variant=variant,
+                            size_class=size_class(nbytes, c.table.bounds))
+        with jax.named_scope(name):
+            return tacc.dispatch(op, x, c.local_axes, c.pod_axis,
+                                 variant=variant, policy=pol, **kw)
     tr = c.tracer if c.tracer is not None else _TRACER
-    if (tr is None and _WATCHDOG is None) or isinstance(x, jax.core.Tracer):
+    if tr is None and _WATCHDOG is None:
         return tacc.dispatch(op, x, c.local_axes, c.pod_axis,
                              variant=variant, policy=pol, **kw)
     with contextlib.ExitStack() as stack:

@@ -87,6 +87,11 @@ def _flash_manual(q, k, v, cfg):
     return compat.manual_region(fwd, in_specs=spec, out_specs=spec)(q, k, v)
 
 
+# Name of the attention core's backward in the compiled program (DESIGN.md
+# §16): the ops of the custom VJP's backward carry it in their HLO op_name.
+ATTENTION_BWD_SCOPE = "attention_bwd"
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _flash(q, k, v, cfg):
     """Flash attention with a custom VJP: the forward is the Pallas kernel;
@@ -102,10 +107,11 @@ def _flash_fwd(q, k, v, cfg):
 def _flash_bwd(cfg, res, g):
     from repro.models.attention import chunked_attention
     kind, window, k_len, chunk, scale = cfg[:5]
-    _, vjp = jax.vjp(functools.partial(
-        chunked_attention, kind=kind, window=window, k_len=k_len,
-        chunk=chunk, scale=scale), *res)
-    return vjp(g)
+    with jax.named_scope(ATTENTION_BWD_SCOPE):
+        _, vjp = jax.vjp(functools.partial(
+            chunked_attention, kind=kind, window=window, k_len=k_len,
+            chunk=chunk, scale=scale), *res)
+        return vjp(g)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

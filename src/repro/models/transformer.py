@@ -29,6 +29,14 @@ from repro.models import ssm as ssm_mod
 from repro.models.common import (ParamMeta, apply_rope, embed_lookup, is_meta,
                                  rms_norm, spec_tree)
 
+# Names of the model's layers in the compiled program (DESIGN.md §16): each
+# op a layer emits, forward, recompute and (through the transpose) backward,
+# carries its scope in its HLO op_name, where a device trace finds it.
+ATTENTION_SCOPE = "attention"
+MLP_SCOPE = "mlp"
+LM_HEAD_SCOPE = "lm_head"
+EMBED_SCOPE = "embed"
+
 
 @dataclasses.dataclass(frozen=True)
 class Ctx:
@@ -250,43 +258,47 @@ def attn_sublayer(p, h, positions, cfg: ModelConfig, ctx: Ctx, *,
 
     cache: (k, v) buffers for decode; pos: current cache length (scalar).
     """
-    q, k, v = _qkv(p, h, positions, cfg, ctx)
-    q = ctx.wsc(q, "batch", "seq", "q_heads", None)
-    new_cache = None
-    if cache is None:
-        out = attn_mod.attention(q, k, v, kind=kind, window=cfg.window,
-                                 chunk=cfg.attn_chunk)
-    else:
-        ck, cv = cache
-        if cfg.window and ck.shape[1] == cfg.window:
-            ck, cv = attn_mod.window_cache_update(ck, cv, k, v, pos)
-            out = attn_mod.window_decode_attention(q, ck, cv, pos, cfg.window)
-        else:
-            ck, cv = attn_mod.cache_update(ck, cv, k, v, pos)
-            out = attn_mod.attention(q, ck, cv, kind=kind, window=cfg.window,
-                                     q_offset=pos, k_len=pos + q.shape[1],
+    with jax.named_scope(ATTENTION_SCOPE):
+        q, k, v = _qkv(p, h, positions, cfg, ctx)
+        q = ctx.wsc(q, "batch", "seq", "q_heads", None)
+        new_cache = None
+        if cache is None:
+            out = attn_mod.attention(q, k, v, kind=kind, window=cfg.window,
                                      chunk=cfg.attn_chunk)
-        new_cache = (ck, cv)
-    proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(h.dtype))
-    if "bo" in p:
-        proj = proj + p["bo"].astype(h.dtype)
-    return proj, new_cache
+        else:
+            ck, cv = cache
+            if cfg.window and ck.shape[1] == cfg.window:
+                ck, cv = attn_mod.window_cache_update(ck, cv, k, v, pos)
+                out = attn_mod.window_decode_attention(q, ck, cv, pos,
+                                                       cfg.window)
+            else:
+                ck, cv = attn_mod.cache_update(ck, cv, k, v, pos)
+                out = attn_mod.attention(q, ck, cv, kind=kind,
+                                         window=cfg.window, q_offset=pos,
+                                         k_len=pos + q.shape[1],
+                                         chunk=cfg.attn_chunk)
+            new_cache = (ck, cv)
+        proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(h.dtype))
+        if "bo" in p:
+            proj = proj + p["bo"].astype(h.dtype)
+        return proj, new_cache
 
 
 def mlp_sublayer(p, h, cfg: ModelConfig, ctx: Ctx):
     """FFN over pre-normed input.  Gated-SiLU if w3 present, else GELU."""
-    h1 = jnp.einsum("bsd,df->bsf", h, p["w1"].astype(h.dtype))
-    if "b1" in p:
-        h1 = h1 + p["b1"].astype(h.dtype)
-    if "w3" in p:
-        h3 = jnp.einsum("bsd,df->bsf", h, p["w3"].astype(h.dtype))
-        hh = jax.nn.silu(h1.astype(jnp.float32)).astype(h.dtype) * h3
-    else:
-        hh = jax.nn.gelu(h1.astype(jnp.float32)).astype(h.dtype)
-    out = jnp.einsum("bsf,fd->bsd", hh, p["w2"].astype(h.dtype))
-    if "b2" in p:
-        out = out + p["b2"].astype(h.dtype)
-    return out
+    with jax.named_scope(MLP_SCOPE):
+        h1 = jnp.einsum("bsd,df->bsf", h, p["w1"].astype(h.dtype))
+        if "b1" in p:
+            h1 = h1 + p["b1"].astype(h.dtype)
+        if "w3" in p:
+            h3 = jnp.einsum("bsd,df->bsf", h, p["w3"].astype(h.dtype))
+            hh = jax.nn.silu(h1.astype(jnp.float32)).astype(h.dtype) * h3
+        else:
+            hh = jax.nn.gelu(h1.astype(jnp.float32)).astype(h.dtype)
+        out = jnp.einsum("bsf,fd->bsd", hh, p["w2"].astype(h.dtype))
+        if "b2" in p:
+            out = out + p["b2"].astype(h.dtype)
+        return out
 
 
 def dense_block(p, x, positions, cfg, ctx, cache=None, pos=None):
@@ -422,7 +434,8 @@ def forward_lm(params, tokens, cfg: ModelConfig, ctx: Ctx, *, mrope=None,
     """
     positions = _positions_for(cfg, tokens, mrope=mrope)
     dtype = jnp.dtype(cfg.dtype)
-    x = embed_lookup(params["embed"], tokens).astype(dtype)
+    with jax.named_scope(EMBED_SCOPE):
+        x = embed_lookup(params["embed"], tokens).astype(dtype)
     x = ctx.wsc(x, "batch", "seq", None)
     gplans = _blocks_gplan(cfg, ctx.rules) if ctx.manual else None
 
@@ -482,43 +495,45 @@ def forward_lm(params, tokens, cfg: ModelConfig, ctx: Ctx, *, mrope=None,
     else:
         raise ValueError(cfg.family)
 
-    x = rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps)
+    with jax.named_scope(LM_HEAD_SCOPE):
+        x = rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps)
     return x, aux
 
 
 def lm_loss_from_hidden(params, x, labels, mask, cfg: ModelConfig, ctx: Ctx):
     """Chunked cross-entropy.  Returns (sum of token losses, token count)."""
-    B, S, D = x.shape
-    T = B * S
-    xf = x.reshape(T, D)
-    lf = labels.reshape(T)
-    mf = mask.reshape(T).astype(jnp.float32)
-    chunk = min(cfg.loss_chunk, T)
-    n = -(-T // chunk)
-    padT = n * chunk - T
-    if padT:
-        xf = jnp.pad(xf, ((0, padT), (0, 0)))
-        lf = jnp.pad(lf, (0, padT))
-        mf = jnp.pad(mf, (0, padT))
-    xc = xf.reshape(n, chunk, D)
-    lc = lf.reshape(n, chunk)
-    mc = mf.reshape(n, chunk)
-    head = params["lm_head"]
+    with jax.named_scope(LM_HEAD_SCOPE):
+        B, S, D = x.shape
+        T = B * S
+        xf = x.reshape(T, D)
+        lf = labels.reshape(T)
+        mf = mask.reshape(T).astype(jnp.float32)
+        chunk = min(cfg.loss_chunk, T)
+        n = -(-T // chunk)
+        padT = n * chunk - T
+        if padT:
+            xf = jnp.pad(xf, ((0, padT), (0, 0)))
+            lf = jnp.pad(lf, (0, padT))
+            mf = jnp.pad(mf, (0, padT))
+        xc = xf.reshape(n, chunk, D)
+        lc = lf.reshape(n, chunk)
+        mc = mf.reshape(n, chunk)
+        head = params["lm_head"]
 
-    pad_mask = (jnp.arange(cfg.padded_vocab) >= cfg.vocab)
+        pad_mask = (jnp.arange(cfg.padded_vocab) >= cfg.vocab)
 
-    def body(acc, inp):
-        xs, ls, ms = inp
-        logits = (xs @ head.astype(xs.dtype)).astype(jnp.float32)
-        logits = ctx.wsc(logits, None, "vocab")
-        logits = jnp.where(pad_mask[None, :], -1e30, logits)  # mask vocab pad
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, ls[:, None], axis=-1)[:, 0]
-        return acc + jnp.sum((lse - gold) * ms), None
+        def body(acc, inp):
+            xs, ls, ms = inp
+            logits = (xs @ head.astype(xs.dtype)).astype(jnp.float32)
+            logits = ctx.wsc(logits, None, "vocab")
+            logits = jnp.where(pad_mask[None, :], -1e30, logits)  # vocab pad
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, ls[:, None], axis=-1)[:, 0]
+            return acc + jnp.sum((lse - gold) * ms), None
 
-    loss_sum, _ = jax.lax.scan(jax.checkpoint(body),
-                               jnp.zeros((), jnp.float32), (xc, lc, mc))
-    return loss_sum, jnp.sum(mf)
+        loss_sum, _ = jax.lax.scan(jax.checkpoint(body),
+                                   jnp.zeros((), jnp.float32), (xc, lc, mc))
+        return loss_sum, jnp.sum(mf)
 
 
 def lm_logits(params, x, cfg: ModelConfig, ctx: Ctx):

@@ -11,11 +11,12 @@ span carries its own modeled-vs-measured residual, the per-dispatch analogue
 of the PR-7 calibration rows (DESIGN.md §14).
 
 The dispatch hook lives in ``repro.core.hetccl._call`` (mirroring the
-watchdog hook, DESIGN.md §15): every **eager** dispatch is recorded; traced
-dispatches (inside jit) pass through untraced — the per-call wall time there
-belongs to XLA's whole step, not to one collective (the elastic loop's
-telemetry probes exist to keep eager per-cell evidence flowing in real
-runs, ``repro.obs.probe``).
+watchdog hook, DESIGN.md §15): every **eager** dispatch is recorded here.
+Traced dispatches (inside jit) are not spans — the per-call wall time there
+belongs to XLA's whole step — but they are *named*: each runs under a
+``hetccl.<op>.<size_class>.<variant>`` scope of the compiled program, and a
+profiler's device trace times it (the elastic loop's telemetry probes still
+keep eager per-cell evidence flowing in real runs, ``repro.obs.probe``).
 
 jax-free and stdlib-pure: the simulator import is lazy and numpy-only.
 """

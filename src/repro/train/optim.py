@@ -32,6 +32,11 @@ from repro.configs.base import RunConfig
 from repro.core import hetccl
 from repro.kernels import quant
 
+# Name of the optimizer step in the compiled program (DESIGN.md §16): clip,
+# global norm, AdamW and the shard slice carry it in their HLO op_name; the
+# collectives issued from inside keep their own ``hetccl.*`` scopes below it.
+SCOPE = "optimizer"
+
 
 def ef_codec(rc: RunConfig) -> str | None:
     """The wire codec error feedback compensates for, or None when EF is off
@@ -166,43 +171,44 @@ def zero1_step(params, grads, opt, step, rc: RunConfig, cfg):
     (new_params, new_opt).  Collectives: HetCCL AllReduce + AllGather.
     ``cfg``: the program's ``repro.comm.Communicator`` (or a legacy
     ``HetCCLConfig``) — every collective resolves its policy from it."""
-    rank, world = dp_rank_and_world(cfg.dp_axes())
-    ef = opt.get("ef")
-    if ef is not None:
-        grads, ef = ef_apply(grads, ef, ef_codec(rc))
-    grads = hetccl.tree_all_reduce(grads, cfg)
+    with jax.named_scope(SCOPE):
+        rank, world = dp_rank_and_world(cfg.dp_axes())
+        ef = opt.get("ef")
+        if ef is not None:
+            grads, ef = ef_apply(grads, ef, ef_codec(rc))
+        grads = hetccl.tree_all_reduce(grads, cfg)
 
-    gnorm = global_norm(grads)
-    scale = clip_scale(gnorm, rc.grad_clip)
+        gnorm = global_norm(grads)
+        scale = clip_scale(gnorm, rc.grad_clip)
 
-    def one(p, g, m, v, master):
-        flat = g.reshape(-1).astype(jnp.float32) * scale
-        pad = _pad_len(flat.size, world) - flat.size
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-        shard = flat.size // world
-        g_sh = lax.dynamic_slice(flat, (rank * shard,), (shard,))
-        decay = 0.0 if p.ndim <= 1 else 1.0     # no decay on norms/biases
-        new_master, m, v = adam_update(g_sh, m, v, master, step, rc, decay)
-        # parameter AllGather (the ZeRO-1 optimizer-state gather, Table 3)
-        full = hetccl.all_gather(new_master.astype(p.dtype), cfg, dim=0)
-        full = full[:p.size].reshape(p.shape)
-        return full, m, v, new_master
+        def one(p, g, m, v, master):
+            flat = g.reshape(-1).astype(jnp.float32) * scale
+            pad = _pad_len(flat.size, world) - flat.size
+            if pad:
+                flat = jnp.pad(flat, (0, pad))
+            shard = flat.size // world
+            g_sh = lax.dynamic_slice(flat, (rank * shard,), (shard,))
+            decay = 0.0 if p.ndim <= 1 else 1.0     # no decay on norms/biases
+            new_master, m, v = adam_update(g_sh, m, v, master, step, rc, decay)
+            # parameter AllGather (the ZeRO-1 optimizer-state gather, Table 3)
+            full = hetccl.all_gather(new_master.astype(p.dtype), cfg, dim=0)
+            full = full[:p.size].reshape(p.shape)
+            return full, m, v, new_master
 
-    leaves_p, tdef = jax.tree.flatten(params)
-    leaves_g = tdef.flatten_up_to(grads)
-    leaves_m = tdef.flatten_up_to(opt["m"])
-    leaves_v = tdef.flatten_up_to(opt["v"])
-    leaves_ms = tdef.flatten_up_to(opt["master"])
-    out = [one(p, g, m, v, ms) for p, g, m, v, ms in
-           zip(leaves_p, leaves_g, leaves_m, leaves_v, leaves_ms)]
-    new_p = tdef.unflatten([o[0] for o in out])
-    new_opt = {"m": tdef.unflatten([o[1] for o in out]),
-               "v": tdef.unflatten([o[2] for o in out]),
-               "master": tdef.unflatten([o[3] for o in out])}
-    if ef is not None:
-        new_opt["ef"] = ef
-    return new_p, new_opt, gnorm
+        leaves_p, tdef = jax.tree.flatten(params)
+        leaves_g = tdef.flatten_up_to(grads)
+        leaves_m = tdef.flatten_up_to(opt["m"])
+        leaves_v = tdef.flatten_up_to(opt["v"])
+        leaves_ms = tdef.flatten_up_to(opt["master"])
+        out = [one(p, g, m, v, ms) for p, g, m, v, ms in
+               zip(leaves_p, leaves_g, leaves_m, leaves_v, leaves_ms)]
+        new_p = tdef.unflatten([o[0] for o in out])
+        new_opt = {"m": tdef.unflatten([o[1] for o in out]),
+                   "v": tdef.unflatten([o[2] for o in out]),
+                   "master": tdef.unflatten([o[3] for o in out])}
+        if ef is not None:
+            new_opt["ef"] = ef
+        return new_p, new_opt, gnorm
 
 
 # ---------------------------------------------------------------------------
@@ -224,37 +230,38 @@ def zero3_step(params, grads, opt, step, rc: RunConfig, cfg, fsdp_leaf_mask):
       replicated leaves-> AllReduce over ('data','pod').
     ``cfg``: communicator (or legacy config); the pod-only projection is a
     ``dataclasses.replace`` like before."""
-    pod_cfg = dataclasses.replace(cfg, local_axes=())
-    ef = opt.get("ef")
-    if ef is not None:
-        # compensates the pod-stage ring (the fsdp reduce-scatter adjoint
-        # quantizes inside autodiff, out of EF's reach — DESIGN.md §17)
-        grads, ef = ef_apply(grads, ef, ef_codec(rc))
-    def sync(g, is_fsdp):
-        if cfg.pod_axis:
-            g = hetccl.all_reduce(g, pod_cfg if is_fsdp else cfg)
-        elif not is_fsdp:
-            g = hetccl.all_reduce(g, cfg)
-        return g
+    with jax.named_scope(SCOPE):
+        pod_cfg = dataclasses.replace(cfg, local_axes=())
+        ef = opt.get("ef")
+        if ef is not None:
+            # compensates the pod-stage ring (the fsdp reduce-scatter adjoint
+            # quantizes inside autodiff, out of EF's reach — DESIGN.md §17)
+            grads, ef = ef_apply(grads, ef, ef_codec(rc))
+        def sync(g, is_fsdp):
+            if cfg.pod_axis:
+                g = hetccl.all_reduce(g, pod_cfg if is_fsdp else cfg)
+            elif not is_fsdp:
+                g = hetccl.all_reduce(g, cfg)
+            return g
 
-    grads = jax.tree.map(sync, grads, fsdp_leaf_mask)
-    gnorm = global_norm_sharded(grads, fsdp_leaf_mask, cfg)
-    scale = clip_scale(gnorm, rc.grad_clip)
+        grads = jax.tree.map(sync, grads, fsdp_leaf_mask)
+        gnorm = global_norm_sharded(grads, fsdp_leaf_mask, cfg)
+        scale = clip_scale(gnorm, rc.grad_clip)
 
-    def one(p, g, m, v, master):
-        decay = 0.0 if p.ndim <= 1 else 1.0
-        new_master, m, v = adam_update(g.astype(jnp.float32) * scale, m, v,
-                                       master, step, rc, decay)
-        return new_master.astype(p.dtype), m, v, new_master
+        def one(p, g, m, v, master):
+            decay = 0.0 if p.ndim <= 1 else 1.0
+            new_master, m, v = adam_update(g.astype(jnp.float32) * scale, m, v,
+                                           master, step, rc, decay)
+            return new_master.astype(p.dtype), m, v, new_master
 
-    flat = jax.tree.map(one, params, grads, opt["m"], opt["v"], opt["master"])
-    new_p = jax.tree.map(lambda o: o[0], flat, is_leaf=lambda x: isinstance(x, tuple))
-    new_opt = {"m": jax.tree.map(lambda o: o[1], flat, is_leaf=lambda x: isinstance(x, tuple)),
-               "v": jax.tree.map(lambda o: o[2], flat, is_leaf=lambda x: isinstance(x, tuple)),
-               "master": jax.tree.map(lambda o: o[3], flat, is_leaf=lambda x: isinstance(x, tuple))}
-    if ef is not None:
-        new_opt["ef"] = ef
-    return new_p, new_opt, gnorm
+        flat = jax.tree.map(one, params, grads, opt["m"], opt["v"], opt["master"])
+        new_p = jax.tree.map(lambda o: o[0], flat, is_leaf=lambda x: isinstance(x, tuple))
+        new_opt = {"m": jax.tree.map(lambda o: o[1], flat, is_leaf=lambda x: isinstance(x, tuple)),
+                   "v": jax.tree.map(lambda o: o[2], flat, is_leaf=lambda x: isinstance(x, tuple)),
+                   "master": jax.tree.map(lambda o: o[3], flat, is_leaf=lambda x: isinstance(x, tuple))}
+        if ef is not None:
+            new_opt["ef"] = ef
+        return new_p, new_opt, gnorm
 
 
 # ---------------------------------------------------------------------------

@@ -32,6 +32,10 @@ from repro.models import Ctx, Model
 from repro.models.common import make_rules, manual_only, spec_tree
 from repro.train import optim
 
+# Name of the step's token-count and loss reductions in the compiled program
+# (DESIGN.md §16); the model's layers and the optimizer name their own ops.
+STEP_STATS_SCOPE = "step_stats"
+
 
 @dataclasses.dataclass
 class TrainProgram:
@@ -184,8 +188,9 @@ def make_train_program(model: Model, mesh, rc: RunConfig, plan: HetPlan,
             micro, (g0, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
             (mb_tree, live))
 
-        total_tokens = lax.psum(count, manual_axes)
-        loss_total = lax.psum(loss_sum, manual_axes)
+        with jax.named_scope(STEP_STATS_SCOPE):
+            total_tokens = lax.psum(count, manual_axes)
+            loss_total = lax.psum(loss_sum, manual_axes)
         inv = 1.0 / jnp.maximum(total_tokens, 1.0)
         grads = jax.tree.map(lambda g: g * inv, grads)
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core import compat, tacc
 from repro.kernels import ref
 from repro.kernels.collective_reduce import collective_reduce as _cr_pallas
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import (flash_attention_bwd,
+                                           flash_attention_fwd)
 from repro.kernels.grouped_matmul import grouped_matmul as _gmm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
@@ -40,10 +42,10 @@ def _pad_to(x, multiple: int, axis: int):
 def flash_attention(q, k, v, *, kind="causal", window=0, q_offset=0,
                     k_offset=0, k_len=None, chunk=None, scale=None,
                     interpret=False, bq=128, bk=128):
-    """Model-layout wrapper for the Pallas flash kernel.
+    """Model-layout wrapper for the Pallas flash kernels.
 
     Decode (Sq < 8) and offset cases fall back to the chunked-jnp path —
-    the kernel targets the big training/prefill shapes.  Differentiable:
+    the kernels target the big training/prefill shapes.  Differentiable:
     see :func:`_flash` for the backward.
     """
     from repro.models.attention import chunked_attention
@@ -52,39 +54,60 @@ def flash_attention(q, k, v, *, kind="causal", window=0, q_offset=0,
         return chunked_attention(q, k, v, kind=kind, window=window,
                                  q_offset=q_offset, k_offset=k_offset,
                                  k_len=k_len, chunk=chunk or 512, scale=scale)
-    cfg = (kind, window, k_len, chunk or 512, scale, bq, bk, interpret)
+    cfg = _FlashCfg(kind, window, k.shape[1] if k_len is None else k_len,
+                    scale, bq, bk, interpret, k.shape[1])
     return _flash(q, k, v, cfg)
 
 
-def _flash_manual(q, k, v, cfg):
-    """The Pallas forward, in a region where every mesh axis is manual
-    (Mosaic kernels cannot be auto-partitioned): heads split over the auto
-    axes when both head counts divide, otherwise every auto-axis rank runs
-    the whole call."""
-    kind, window, k_len, _, scale, bq, bk, interpret = cfg
-    Sq = q.shape[1]
+class _FlashCfg(NamedTuple):
+    kind: str
+    window: int
+    k_len: int              # valid kv prefix
+    scale: float | None
+    bq: int                 # the forward's blocks
+    bk: int
+    interpret: bool
+    sk: int                 # kv length before padding
 
-    def fwd(q, k, v):
-        qt = jnp.moveaxis(q, 1, 2)
-        kt = jnp.moveaxis(k, 1, 2)
-        vt = jnp.moveaxis(v, 1, 2)
-        qt, pq = _pad_to(qt, bq, 2)
-        kt, _ = _pad_to(kt, bk, 2)
-        vt, _ = _pad_to(vt, bk, 2)
-        out = flash_attention_fwd(
-            qt, kt, vt, kind=kind, window=window,
-            k_len=k.shape[1] if k_len is None else k_len, scale=scale,
-            bq=bq, bk=bk, interpret=interpret)
-        if pq:
-            out = out[:, :, :Sq]
-        return jnp.moveaxis(out, 1, 2)
 
+def _to_kernel(x, block):
+    """(B, S, H, d) -> (B, H, S, d), S padded to a multiple of ``block``."""
+    return _pad_to(jnp.moveaxis(x, 1, 2), block, 2)[0]
+
+
+def _from_kernel(x, s):
+    """(B, H, S_padded, d) -> (B, s, H, d)."""
+    return jnp.moveaxis(x[:, :, :s], 1, 2)
+
+
+def _head_specs(hq, hkv):
+    """Specs of the all-manual region the kernels run in (Mosaic kernels
+    cannot be auto-partitioned), in model and in kernel layout: heads split
+    over the auto axes when both head counts divide, otherwise every
+    auto-axis rank runs the whole call."""
     auto = compat.auto_axes()
     n_auto = math.prod(auto.values())
-    spec = P()
-    if n_auto > 1 and q.shape[2] % n_auto == 0 and k.shape[2] % n_auto == 0:
-        spec = P(None, None, tuple(auto), None)
-    return compat.manual_region(fwd, in_specs=spec, out_specs=spec)(q, k, v)
+    if n_auto > 1 and hq % n_auto == 0 and hkv % n_auto == 0:
+        return P(None, None, tuple(auto), None), P(None, tuple(auto), None, None)
+    return P(), P()
+
+
+def _flash_manual(q, k, v, cfg, residuals=False):
+    """The Pallas forward in model layout; with ``residuals`` also the
+    backward's residuals (q, k, v, o, lse) in kernel layout, padded."""
+    def fwd(q, k, v):
+        qt, kt, vt = (_to_kernel(q, cfg.bq), _to_kernel(k, cfg.bk),
+                      _to_kernel(v, cfg.bk))
+        ot, lse = flash_attention_fwd(
+            qt, kt, vt, kind=cfg.kind, window=cfg.window, k_len=cfg.k_len,
+            scale=cfg.scale, bq=cfg.bq, bk=cfg.bk, interpret=cfg.interpret)
+        out = _from_kernel(ot, q.shape[1])
+        return (out, (qt, kt, vt, ot, lse)) if residuals else out
+
+    model, kern = _head_specs(q.shape[2], k.shape[2])
+    out_specs = (model, (kern,) * 5) if residuals else model
+    return compat.manual_region(fwd, in_specs=model,
+                                out_specs=out_specs)(q, k, v)
 
 
 # Name of the attention core's backward in the compiled program (DESIGN.md
@@ -94,24 +117,30 @@ ATTENTION_BWD_SCOPE = "attention_bwd"
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _flash(q, k, v, cfg):
-    """Flash attention with a custom VJP: the forward is the Pallas kernel;
-    the backward is the VJP of ``chunked_attention`` (the same online-softmax
-    math in jnp, recomputed from q, k, v) — not a Pallas backward kernel."""
+    """Flash attention with a custom VJP: the forward is the Pallas kernel
+    (its logsumexp output dropped); the backward is the Pallas dK/dV and dQ
+    kernels (``flash_attention_bwd``), which recompute the probabilities
+    from q, k and the forward's logsumexp."""
     return _flash_manual(q, k, v, cfg)
 
 
 def _flash_fwd(q, k, v, cfg):
-    return _flash_manual(q, k, v, cfg), (q, k, v)
+    return _flash_manual(q, k, v, cfg, residuals=True)
 
 
 def _flash_bwd(cfg, res, g):
-    from repro.models.attention import chunked_attention
-    kind, window, k_len, chunk, scale = cfg[:5]
+    def bwd(qt, kt, vt, ot, lse, g):
+        dq, dk, dv = flash_attention_bwd(
+            qt, kt, vt, ot, lse, _to_kernel(g, cfg.bq), kind=cfg.kind,
+            window=cfg.window, k_len=cfg.k_len, scale=cfg.scale,
+            interpret=cfg.interpret)
+        return (_from_kernel(dq, g.shape[1]), _from_kernel(dk, cfg.sk),
+                _from_kernel(dv, cfg.sk))
+
+    model, kern = _head_specs(g.shape[2], res[1].shape[1])
     with jax.named_scope(ATTENTION_BWD_SCOPE):
-        _, vjp = jax.vjp(functools.partial(
-            chunked_attention, kind=kind, window=window, k_len=k_len,
-            chunk=chunk, scale=scale), *res)
-        return vjp(g)
+        return compat.manual_region(bwd, in_specs=(kern,) * 5 + (model,),
+                                    out_specs=(model,) * 3)(*res, g)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

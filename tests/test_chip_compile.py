@@ -8,6 +8,7 @@ The topology is described inside a module-scoped fixture (never at import):
 only the worker that runs these tests loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import compat, tacc
-from repro.kernels import ops, quant, ring_dma
+from repro.kernels import flash_attention, ops, quant, ring_dma
 
 BUCKET_ELEMS = (64 << 20) // 4          # the default 64 MiB f32 bucket
 
@@ -73,8 +74,11 @@ def test_flash_attention_vjp_compiles(one_chip):
         return jnp.sum(ops.flash_attention(q, k, v).astype(jnp.float32))
 
     # value_and_grad: the forward value keeps the kernel live in the program
-    assert "tpu_custom_call" in _hlo(
-        jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    text = _hlo(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert "tpu_custom_call" in text
+    for name in (flash_attention.KERNEL_NAME, flash_attention.BWD_DKV_KERNEL_NAME,
+                 flash_attention.BWD_DQ_KERNEL_NAME):
+        assert re.search(rf'op_name="[^"]*{name}\)?/pallas_call"', text), name
 
 
 def test_collective_reduce_compiles(one_chip):

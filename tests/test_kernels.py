@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import flash_attention_bwd, flash_attention_fwd
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 rng = np.random.RandomState(0)
@@ -21,8 +21,8 @@ def test_flash_attention_sweep(kind, window, dtype, B, Hq, Hkv, S, d):
     k = (rng.randn(B, Hkv, S, d) * 0.5).astype(np.float32)
     v = (rng.randn(B, Hkv, S, d) * 0.5).astype(np.float32)
     qj, kj, vj = (jnp.asarray(t).astype(dtype) for t in (q, k, v))
-    got = flash_attention_fwd(qj, kj, vj, kind=kind, window=window,
-                              bq=128, bk=128, interpret=True)
+    got, _ = flash_attention_fwd(qj, kj, vj, kind=kind, window=window,
+                                 bq=128, bk=128, interpret=True)
     want = ref.attention(qj, kj, vj, kind=kind, window=window)
     atol = 3e-4 if dtype == np.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -32,7 +32,7 @@ def test_flash_attention_sweep(kind, window, dtype, B, Hq, Hkv, S, d):
 def test_flash_attention_k_len():
     B, H, S, d = 1, 2, 256, 64
     q, k, v = (jnp.asarray(rng.randn(B, H, S, d), jnp.float32) for _ in range(3))
-    got = flash_attention_fwd(q, k, v, kind="bidir", k_len=77, interpret=True)
+    got, _ = flash_attention_fwd(q, k, v, kind="bidir", k_len=77, interpret=True)
     want = ref.attention(q, k, v, kind="bidir", k_len=77)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-4)
 
@@ -101,6 +101,97 @@ def test_flash_attention_in_partially_manual_region(mesh3, Hq, Hkv):
     val, *grads = jax.jit(sm)(q, k, v, w)
     want = _attention_grads(dense_reference, q, k, v, w)
     _assert_grads_close((val, grads), want, atol=1e-3)
+
+
+def _bmodel(x):
+    """Kernel layout (B, H, S, d) <-> model layout (B, S, H, d)."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _assert_rel_close(got, want, rtol):
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=rtol * np.abs(b).max())
+
+
+# gradient tolerance relative to each gradient's largest entry: bf16 rounds
+# the operands, P and dS (eps 2^-8) on both sides of the comparison
+BWD_RTOL = {np.float32: 1e-4, jnp.bfloat16: 2e-2}
+
+
+@pytest.mark.parametrize("kind,window", [("causal", 0), ("causal", 64),
+                                         ("bidir", 0)])
+@pytest.mark.parametrize("Hq,Hkv", [(4, 2), (3, 1)])
+@pytest.mark.parametrize("Sq,Sk", [(200, 200), (128, 256)],
+                         ids=["padded", "cross"])
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_flash_attention_bwd_kernels(kind, window, Hq, Hkv, Sq, Sk, dtype):
+    """The dK/dV and dQ kernels' gradients, through the TPU attention entry
+    (model layout, S padded to the blocks), match ``jax.vjp`` of the dense
+    oracle on the same inputs."""
+    from repro.models.attention import dense_reference
+    B, d = 2, 32
+    q = jnp.asarray(rng.randn(B, Sq, Hq, d) * 0.5, jnp.float32).astype(dtype)
+    k = jnp.asarray(rng.randn(B, Sk, Hkv, d) * 0.5, jnp.float32).astype(dtype)
+    v = jnp.asarray(rng.randn(B, Sk, Hkv, d) * 0.5, jnp.float32).astype(dtype)
+    do = jnp.asarray(rng.randn(B, Sq, Hq, d), jnp.float32).astype(dtype)
+    kw = dict(kind=kind, window=window)
+    _, vjp = jax.vjp(lambda q, k, v: ops.flash_attention(
+        q, k, v, interpret=True, **kw), q, k, v)
+    got = jax.jit(vjp)(do)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
+    _, vjp_ref = jax.vjp(lambda q, k, v: dense_reference(q, k, v, **kw), *f32[:3])
+    assert all(g.dtype == x.dtype for g, x in zip(got, (q, k, v)))
+    _assert_rel_close(got, vjp_ref(f32[3]), BWD_RTOL[dtype])
+
+
+@pytest.mark.parametrize("kind,window,S,k_len", [
+    ("causal", 0, 256, None),       # (q block 0, kv block 1): above the diagonal
+    ("causal", 64, 384, None),      # (q block 2, kv block 0): outside the window
+    ("bidir", 0, 256, 77),          # kv block 1: past k_len
+], ids=["diagonal", "window", "k_len"])
+def test_flash_attention_bwd_wholly_masked_blocks(kind, window, S, k_len):
+    """At 128-wide blocks some block pairs are wholly masked (neither
+    fetched nor computed); the gradients still match the oracle's, with
+    zeros for keys past ``k_len``."""
+    from repro.models.attention import dense_reference
+    B, Hq, Hkv, d = 1, 2, 1, 64
+    q, do = (jnp.asarray(rng.randn(B, Hq, S, d) * 0.5, jnp.float32) for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(B, Hkv, S, d) * 0.5, jnp.float32) for _ in range(2))
+    kw = dict(kind=kind, window=window, k_len=k_len)
+    o, lse = flash_attention_fwd(q, k, v, interpret=True, **kw)
+    got = flash_attention_bwd(q, k, v, o, lse, do, bq=128, bk=128,
+                              interpret=True, **kw)
+    _, vjp = jax.vjp(lambda q, k, v: _bmodel(dense_reference(
+        _bmodel(q), _bmodel(k), _bmodel(v), **kw)), q, k, v)
+    want = vjp(do)
+    _assert_rel_close(got, want, BWD_RTOL[np.float32])
+    if k_len is not None:
+        assert not np.asarray(got[1][:, :, k_len:]).any()
+
+
+@pytest.mark.parametrize("kind,window,k_len", [
+    ("causal", 0, 1024), ("causal", 64, 1024), ("causal", 700, 1024),
+    ("bidir", 0, 1024), ("bidir", 0, 600), ("causal", 300, 777)])
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 512),
+                                   (512, 256)])
+def test_flash_attention_bwd_live_ranges(kind, window, k_len, bq, bk):
+    """The index maps clamp the sequential axis to the live range: every
+    live block pair lies inside it (so it is fetched), and with a causal
+    diagonal or a window the dead ones outside it (so they are not)."""
+    from repro.kernels import flash_attention as fa
+    S = 1024
+    n_q, n_k = S // bq, S // bk
+    st = dict(kind=kind, window=window, bq=bq, bk=bk)
+    for i in range(n_q):
+        for j in range(n_k):
+            live = bool(fa._pair(i, j, k_len=k_len, **st)[0])
+            q_lo, q_hi = (int(x) for x in fa._q_range(j, n_q=n_q, **st))
+            k_lo, k_hi = (int(x) for x in fa._k_range(i, k_len=k_len, n_k=n_k, **st))
+            if live:
+                assert q_lo <= i <= q_hi and k_lo <= j <= k_hi, (i, j)
+            elif kind == "causal" and j * bk < k_len:
+                assert not q_lo <= i <= q_hi and not k_lo <= j <= k_hi, (i, j)
 
 
 @pytest.mark.parametrize("G,M,K,N", [(4, 200, 96, 160), (1, 128, 128, 128),

@@ -20,6 +20,8 @@ from repro.configs.base import RunConfig
 from repro.core import compat, tacc
 from repro.core.balance import uniform_plan
 from repro.core.collectives import CROSS_SCOPE, INTRA_SCOPE
+from repro.kernels.flash_attention import (BWD_DKV_KERNEL_NAME,
+                                           BWD_DQ_KERNEL_NAME)
 from repro.kernels.ops import ATTENTION_BWD_SCOPE
 from repro.models import build
 from repro.models.transformer import (ATTENTION_SCOPE, EMBED_SCOPE,
@@ -74,6 +76,18 @@ def test_attention_backward_is_inside_the_attention_layer(paths):
     inner = [p for p in paths if ATTENTION_BWD_SCOPE in p]
     assert all(ATTENTION_SCOPE in p[:p.index(ATTENTION_BWD_SCOPE)]
                for p in inner)
+
+
+@pytest.mark.parametrize("kernel", [BWD_DKV_KERNEL_NAME, BWD_DQ_KERNEL_NAME])
+def test_backward_kernels_are_inside_attention_bwd(paths, kernel):
+    """The Pallas dK/dV and dQ kernels' operations carry the backward's
+    scope, itself inside the attention layer's."""
+    inner = [p for p in paths if kernel in p]
+    assert inner, kernel
+    for p in inner:
+        before = p[:p.index(kernel)]
+        assert ATTENTION_BWD_SCOPE in before
+        assert ATTENTION_SCOPE in before[:before.index(ATTENTION_BWD_SCOPE)]
 
 
 @pytest.mark.parametrize("op,stage", [("reduce_scatter", CROSS_SCOPE),

@@ -17,7 +17,9 @@ against a limit of its own (``bench/limits/<cell>.json``):
   computes with (the program's, gathered across chips; the reference's
   master weights cast to the configuration's dtype), over the same leaves.
 
-A leaf is one layer's slice of a stacked parameter, or a whole parameter.
+A leaf is one layer's slice of a stacked parameter, or a whole parameter;
+which parameters are stacked, the configuration's family says
+(``bench/weights.py`` ``stacked``).
 """
 from __future__ import annotations
 
@@ -29,20 +31,21 @@ NUMBERS = ("loss", "grad", "delta", "param")
 MOVING = 1e-3            # a leaf moves if its reference gradient >= this x median
 
 
-def _flat(per_leaf: dict) -> dict[str, float]:
+def _flat(per_leaf: dict, stacked) -> dict[str, float]:
     out = {}
     for name, vec in per_leaf.items():
         vec = np.atleast_1d(vec)
         for i, x in enumerate(vec):
-            out[f"{name}[{i}]" if name.startswith("layers/") else name] = float(x)
+            out[f"{name}[{i}]" if name in stacked else name] = float(x)
     return out
 
 
-def worst_leaf(prog: dict, ref: dict, keep=None) -> tuple[float, str]:
+def worst_leaf(prog: dict, ref: dict, stacked, keep=None) -> tuple[float, str]:
     """(gap, leaf) of the leaf whose norms differ most, relative to the
     larger of its reference norm and the median leaf's; a non-finite
-    program norm reads infinite."""
-    p, r = _flat(prog), _flat(ref)
+    program norm reads infinite.  ``stacked`` names the parameters whose
+    norms are one per layer."""
+    p, r = _flat(prog, stacked), _flat(ref, stacked)
     names = [n for n in r if keep is None or n in keep]
     med = float(np.median([r[n] for n in names]))
 
@@ -54,17 +57,18 @@ def worst_leaf(prog: dict, ref: dict, keep=None) -> tuple[float, str]:
     return gap(where), where
 
 
-def gaps(prog: dict, ref: dict) -> dict[str, tuple[float, str]]:
+def gaps(prog: dict, ref: dict, stacked) -> dict[str, tuple[float, str]]:
     """``{number: (value, where)}`` for readings of the program (or of a
-    control put in its place) against the reference's."""
+    control put in its place) against the reference's; ``stacked`` names
+    the parameters whose norms are one per layer."""
     loss = max((abs(p - r) / abs(r) if math.isfinite(p) else math.inf, f"step {i}")
                for i, (p, r) in enumerate(zip(prog["loss"], ref["loss"])))
-    g = _flat(ref["grad"])
+    g = _flat(ref["grad"], stacked)
     med = float(np.median(list(g.values())))
     moving = {n for n, x in g.items() if x >= MOVING * med}
-    return {"loss": loss, "grad": worst_leaf(prog["grad"], ref["grad"]),
-            "delta": worst_leaf(prog["delta"], ref["delta"], moving),
-            "param": worst_leaf(prog["param"], ref["param"], moving)}
+    return {"loss": loss, "grad": worst_leaf(prog["grad"], ref["grad"], stacked),
+            "delta": worst_leaf(prog["delta"], ref["delta"], stacked, moving),
+            "param": worst_leaf(prog["param"], ref["param"], stacked, moving)}
 
 
 def verdict(found: dict, limits: dict | None) -> tuple[bool, dict]:

@@ -89,11 +89,11 @@ def _judged(found, limits):
             **{k: {"value": v[0], "where": v[1]} for k, v in found.items()}}
 
 
-def _fault(readings, ref, limits):
+def _fault(readings, ref, stacked, limits):
     """A fault's judged gaps; a fault that crashes has failed."""
     from bench import check
     try:
-        return _judged(check.gaps(readings(), ref), limits)
+        return _judged(check.gaps(readings(), ref, stacked), limits)
     except Exception as e:  # noqa: BLE001 -- any crash is the fault failing
         return {"correct": False, "error": repr(e)}
 
@@ -107,10 +107,11 @@ def main(argv=None) -> int:
     ap.add_argument("--against-seeds", type=int, default=None)
     args = ap.parse_args(argv)
     import jax
-    from bench import check, harness, program as program_mod
+    from bench import check, harness, program as program_mod, weights
 
     cell = harness.load_cell(args.workload)
     arch, job, limits = cell["arch"], cell["job"], cell["limits"]
+    stacked = weights.stacked(arch)
     seeds = [int(s) for s in args.seeds.split(",")]
     against = [f for f in args.against.split(",") if f]
     others = seeds[:args.against_seeds]
@@ -135,18 +136,19 @@ def main(argv=None) -> int:
         t = time.perf_counter()
         refs[seed] = ref = harness.reference_readings(arch, job, seed)
         rec["reference_s"] = time.perf_counter() - t
-        rec["program"] = _judged(check.gaps(prog, ref), limits)
+        rec["program"] = _judged(check.gaps(prog, ref, stacked), limits)
         rec["loss"] = {"program": prog["loss"], "reference": ref["loss"]}
         rec["gnorm_reference"] = ref["gnorm"]
         if seed in others and "control" in against:
             t = time.perf_counter()
             ctl = harness.reference_readings(arch, job, seed, precision="fp8")
             rec["control_s"] = time.perf_counter() - t
-            rec["control"] = _judged(check.gaps(ctl, ref), limits)
+            rec["control"] = _judged(check.gaps(ctl, ref, stacked), limits)
             rec["loss"]["control"] = ctl["loss"]
         for kind, broken in wrapped.items():
             if seed in others:
-                rec[kind] = _fault(lambda: readings_of(broken, seed, steps)[0], ref, limits)
+                rec[kind] = _fault(lambda: readings_of(broken, seed, steps)[0], ref, stacked,
+                                   limits)
         print(json.dumps({"seed": seed, **rec}), flush=True)
         save()
     if "no_exchange" in against:
@@ -154,7 +156,7 @@ def main(argv=None) -> int:
         broken = program_mod.build(arch, job, devices)
         for seed in others:
             out["seeds"][str(seed)]["no_exchange"] = _fault(
-                lambda: readings_of(broken, seed, steps)[0], refs[seed], limits)
+                lambda: readings_of(broken, seed, steps)[0], refs[seed], stacked, limits)
             print(json.dumps({"seed": seed, "no_exchange": out["seeds"][str(seed)]["no_exchange"]}),
                   flush=True)
         save()

@@ -3,8 +3,10 @@
 Everything about a cell is found by name: ``BENCHMARK.json`` names the
 cell's configuration and traffic; the configuration file
 (``bench/configs/<config>.json``) holds the model's published sizes and
-names its plain reference (``bench/references/<reference>.py``); the
-traffic file (``bench/traffic/<traffic>.json``) is the training job; each
+names its block family (``bench/families/<family>.py``: the weights'
+layout, their paths in the program, the program's model config and the
+FLOP count) and its plain reference (``bench/references/<reference>.py``);
+the traffic file (``bench/traffic/<traffic>.json``) is the training job; each
 metric is read by ``bench/metrics/<metric>.py``; the limits of the
 comparison are in ``bench/limits/<cell>.json``.
 
@@ -44,7 +46,10 @@ def _module(path: Path):
 
 def load_cell(name: str, spec: dict | None = None) -> dict:
     """The cell's workload entry, configuration, traffic, metric names and
-    limits, found by name."""
+    limits, found by name: the configuration at its ``file``, the traffic
+    at ``bench/traffic/<traffic>.json``, the limits at
+    ``bench/limits/<cell>.json``.  The configuration names its family and
+    reference modules, found when they are used."""
     spec = spec or json.loads((ROOT / "BENCHMARK.json").read_text())
     wl = {w["name"]: w for w in spec["workloads"]}
     if name not in wl:
@@ -72,7 +77,8 @@ def read_metric(name: str, run) -> float | None:
 
 
 def reference_module(arch: dict):
-    return _module(BENCH / "references" / f"{arch['reference']}.py")
+    """The plain reference that the configuration ``arch`` names."""
+    return importlib.import_module(f"bench.references.{arch['reference']}")
 
 
 @dataclasses.dataclass
@@ -183,7 +189,7 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, devices, *,
             program=None, cache: bool = True) -> dict:
     """One run of ``cell``; returns the result object (not yet printed)."""
     import jax
-    from bench import check, program as program_mod, work
+    from bench import check, program as program_mod, weights, work
     arch, job = cell["arch"], cell["job"]
     devices = list(devices)[:cell["chips"]]
     kind = devices[0].device_kind
@@ -238,7 +244,7 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, devices, *,
         run.reduction = xplane.reduce(tr, classify, devices=[d.id for d in mesh_devices])
 
     ref = reference_readings(arch, job, seed)
-    found = check.gaps(readings, ref)
+    found = check.gaps(readings, ref, weights.stacked(arch))
     correct, shown = check.verdict(found, cell["limits"])
     correct = correct and failed == 0
 

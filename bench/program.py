@@ -1,9 +1,11 @@
 """The benchmark's one door into the program under test (``src/repro``).
 
-Every call the harness makes into the system is in this module, and the
-benchmark depends on these program entry points only:
+Every call the harness makes into the system is in this module or in a
+family module's ``model_config`` (``bench/families/``), which this module
+calls, and the benchmark depends on these program entry points only:
 
-- ``repro.configs.base.ModelConfig`` and ``RunConfig``;
+- ``repro.configs.base.ModelConfig`` (a family's ``model_config``, the only
+  program entry point a family module uses) and ``RunConfig``;
 - ``repro.models.build``;
 - ``repro.core.balance.uniform_plan``;
 - ``repro.core.compat.make_mesh``;
@@ -12,7 +14,8 @@ benchmark depends on these program entry points only:
   layout), ``state_shardings`` and ``batch_sharding``;
 - the ZeRO-1 state layout of ``repro.train.optim``: ``{"params", "opt":
   {"m", "v", "master"}, "step"}``, optimizer leaves flat f32, padded to a
-  multiple of the data-parallel world;
+  multiple of the data-parallel world; the params at the paths the
+  family's ``PATHS`` names;
 - ``repro.launch.cache.enable_compile_cache``;
 - ``repro.core.hetccl.tree_all_reduce`` (planted faults only).
 """
@@ -27,19 +30,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench import families, weights
 
 SRC = Path(__file__).resolve().parents[1] / "src"
-
-# canonical weight name (bench/weights.py) -> path in the program's params
-PATHS = {
-    "embed": ("embed",), "final_norm": ("final_norm",), "lm_head": ("lm_head",),
-    "layers/ln1": ("blocks", "ln1"), "layers/ln2": ("blocks", "ln2"),
-    "layers/wq": ("blocks", "attn", "wq"), "layers/wk": ("blocks", "attn", "wk"),
-    "layers/wv": ("blocks", "attn", "wv"), "layers/wo": ("blocks", "attn", "wo"),
-    "layers/w1": ("blocks", "mlp", "w1"), "layers/w2": ("blocks", "mlp", "w2"),
-    "layers/w3": ("blocks", "mlp", "w3"),
-}
 
 
 def _import_path():
@@ -54,19 +47,9 @@ def enable_compile_cache() -> str:
 
 
 def model_config(arch: dict):
-    """The program's ModelConfig of a Llama-style configuration file."""
+    """The program's ModelConfig of a configuration, as its family maps it."""
     _import_path()
-    from repro.configs.base import ModelConfig
-    if arch.get("tie_word_embeddings"):
-        raise ValueError("the program has no tied LM head")
-    return ModelConfig(
-        name=arch["name"], family="dense",
-        n_layers=arch["num_hidden_layers"], d_model=arch["hidden_size"],
-        n_heads=arch["num_attention_heads"],
-        n_kv_heads=arch["num_key_value_heads"],
-        d_ff=arch["intermediate_size"], vocab=arch["vocab_size"],
-        head_dim=arch.get("head_dim", 0), rope_theta=arch["rope_theta"],
-        norm_eps=arch["rms_norm_eps"], dtype=arch["torch_dtype"])
+    return families.of(arch).model_config(arch)
 
 
 def _set(tree: dict, path: tuple, value):
@@ -99,11 +82,16 @@ class Program:
     def put(self, batch: dict) -> dict:
         return jax.device_put(batch, self.prog.batch_sharding)
 
+    @property
+    def paths(self) -> dict:
+        """Canonical leaf name -> path in the program's params."""
+        return families.of(self.arch).PATHS
+
     # ---- state ------------------------------------------------------------
     def _state_from_weights(self, w: dict) -> dict:
         params: dict = {}
         master, zeros = {}, {}
-        for name, path in PATHS.items():
+        for name, path in self.paths.items():
             _set(params, path, w[name])
             flat = w[name].reshape(-1).astype(jnp.float32)
             pad = -flat.size % self.dp_world
@@ -139,16 +127,17 @@ class Program:
 
     # ---- readouts for the comparison ----------------------------------------
     def _per_leaf(self, fn, tree_a, tree_b=None) -> dict[str, jax.Array]:
-        """``fn(leaf, other, name, shape)`` over the program-layout tree
+        """``fn(leaf, other, shape, stacked)`` over the program-layout tree
         ``tree_a`` (flat, padded optimizer leaves cut to their size) and the
         canonical tree ``tree_b``, keyed by canonical name."""
         shapes = weights.shapes(self.arch)
+        stacked = weights.stacked(self.arch)
         out = {}
-        for name, path in PATHS.items():
+        for name, path in self.paths.items():
             n = int(np.prod(shapes[name]))
             a = _get(tree_a, path)[:n]
             b = None if tree_b is None else tree_b[name]
-            out[name] = fn(a, b, name, shapes[name])
+            out[name] = fn(a, b, shapes[name], name in stacked)
         return out
 
     def grad_norms(self, state) -> dict[str, np.ndarray]:
@@ -158,7 +147,7 @@ class Program:
 
         def norms(opt):
             return self._per_leaf(
-                lambda a, _, n, s: _row_norms(a, n, s) / (1.0 - b1), opt["m"])
+                lambda a, _, s, st: _row_norms(a, s, st) / (1.0 - b1), opt["m"])
         return _host(jax.jit(norms)(state["opt"]))
 
     def delta_norms(self, state, seed: int) -> dict[str, np.ndarray]:
@@ -177,17 +166,18 @@ class Program:
             w0 = {k: v.reshape(-1).astype(jnp.float32) for k, v in w0.items()}
             flat = jax.tree.map(lambda x: x.reshape(-1), tree)
             return self._per_leaf(
-                lambda a, b, n, s: _row_norms(a.astype(jnp.float32) - b, n, s), flat, w0)
+                lambda a, b, s, st: _row_norms(a.astype(jnp.float32) - b, s, st),
+                flat, w0)
         return _host(jax.jit(norms)(tree, *weights.seed_words(seed)))
 
     def hlo_text(self, abstract_state, abstract_batch) -> str:
         return self.prog.step_fn.lower(abstract_state, abstract_batch).compile().as_text()
 
 
-def _row_norms(flat, name: str, shape):
-    """Norm of each layer's slice of a stacked leaf (``layers/*``), or of
-    the whole leaf, as a vector."""
-    if name.startswith("layers/"):
+def _row_norms(flat, shape, stacked: bool):
+    """Norm of each layer's slice of a stacked leaf, or of the whole leaf,
+    as a vector."""
+    if stacked:
         return jnp.sqrt(jnp.sum(jnp.square(flat.reshape(shape[0], -1)), axis=1))
     return jnp.sqrt(jnp.sum(jnp.square(flat)))[None]
 

@@ -89,30 +89,51 @@ def test_run_without_a_tpu_exits_without_a_result():
     assert "TPU" in p.stderr
 
 
-def test_reference_agrees_with_the_program_model():
-    """At a tiny size, in float32, the reference's loss and gradients equal
-    those of the program's model (``repro.models``) on the same weights."""
+@pytest.mark.parametrize("conf", SPEC["configs"], ids=lambda c: c["name"])
+def test_every_configuration_names_its_family(conf):
+    """Each configuration names a family module (``bench/families/``) that
+    holds the whole block: layout, paths, model config, FLOP count and CPU
+    size, one path for each canonical leaf."""
+    from bench import families
+    arch = json.loads((ROOT / conf["file"]).read_text())
+    assert (BENCH / "families" / f"{arch['family']}.py").exists()
+    fam = families.of(arch)
+    for attr in ("LAYOUT", "PATHS", "model_config", "model_flops_per_token", "TINY"):
+        assert hasattr(fam, attr), (arch["family"], attr)
+    assert set(fam.LAYOUT) == set(fam.PATHS)
+    for name, (shape, init, stacked) in fam.LAYOUT.items():
+        assert init in ("normal", "ones") and isinstance(stacked, bool), name
+        dims = shape({**arch, **fam.TINY})
+        assert all(isinstance(d, int) and d > 0 for d in dims), name
+        assert len(dims) >= 1 + stacked, name
+    assert fam.model_flops_per_token(arch, 2048) > 0
+
+
+@pytest.mark.parametrize("conf", SPEC["configs"], ids=lambda c: c["name"])
+def test_reference_agrees_with_the_program_model(conf):
+    """At the family's tiny size, in float32, the configuration's reference
+    gives the loss and gradients of the program's model (``repro.models``)
+    on the same weights."""
     import jax
     import jax.numpy as jnp
-    from bench import feed, program, weights
-    from bench.references import llama_dense
+    from bench import families, feed, harness, program, weights
     program._import_path()
     from repro.core import compat
     from repro.models import build
     from repro.models.common import make_rules
     from repro.models.transformer import Ctx
 
-    arch = json.loads((BENCH / "configs" / "smollm-135m.json").read_text())
-    arch.update(hidden_size=64, intermediate_size=96, num_attention_heads=4,
-                num_key_value_heads=2, num_hidden_layers=2, vocab_size=256,
-                torch_dtype="float32")
+    arch = json.loads((ROOT / conf["file"]).read_text())
+    fam = families.of(arch)
+    arch.update(fam.TINY, torch_dtype="float32")
+    ref = harness.reference_module(arch)
     cfg = program.model_config(arch)
     model = build(cfg)
     mesh = compat.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     ctx = Ctx(rules=make_rules(cfg, mesh, 1), manual=False)
     w = weights.make(arch, weights.seed_key(*weights.seed_words(2**33 + 7)), jnp.float32)
     params = {}
-    for name, path in program.PATHS.items():
+    for name, path in fam.PATHS.items():
         program._set(params, path, w[name])
     batch = feed.synthetic_batch(5, 0, 1, 2, 32, arch["vocab_size"])
     tokens, labels = jnp.asarray(batch["tokens"][0]), jnp.asarray(batch["labels"][0])
@@ -122,9 +143,9 @@ def test_reference_agrees_with_the_program_model():
             loss_sum, _, _ = model.loss(p, {"tokens": tokens, "labels": labels}, ctx)
             return loss_sum
         l_p, g_p = jax.value_and_grad(theirs)(params)
-        l_r, g_r = jax.value_and_grad(llama_dense.loss_sum)(w, tokens, labels, arch,
-                                                            llama_dense.MATMULS["f32"])
+        l_r, g_r = jax.value_and_grad(ref.loss_sum)(w, tokens, labels, arch,
+                                                    ref.MATMULS["f32"])
     assert float(l_r) == pytest.approx(float(l_p), rel=1e-5)
-    for name, path in program.PATHS.items():
+    for name, path in fam.PATHS.items():
         a, b = np.asarray(program._get(g_p, path)), np.asarray(g_r[name])
         assert np.linalg.norm(a - b) <= 1e-4 * np.linalg.norm(b), name

@@ -22,14 +22,13 @@ import pytest  # noqa: E402
 ROOT = Path(__file__).resolve().parents[1]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
-TINY = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
-            num_key_value_heads=2, num_hidden_layers=2, vocab_size=256)
 
 
 def tiny_cell(name):
-    from bench import harness
+    """The cell at its family's CPU size (``TINY``), seq 64."""
+    from bench import families, harness
     cell = harness.load_cell(name)
-    cell["arch"].update(TINY)
+    cell["arch"].update(families.of(cell["arch"]).TINY)
     cell["job"].update(seq=64)
     cell["job"]["backend"] = "xla"          # the DMA rings run only on a TPU
     return cell
@@ -131,11 +130,13 @@ def test_control_reads_above_the_program(name, built):
     a test run can hold: the cell's limits hold at the cell's own size,
     where ``bench/control.py`` requires the control to come out not
     correct."""
-    from bench import check, harness
+    from bench import check, harness, weights
     cell, program = built(name)
     seed = 2**32 + 5
     state, prog, _ = harness.program_readings(program, seed, cell["job"]["check_steps"])
     harness.free(state)
     ref = harness.reference_readings(cell["arch"], cell["job"], seed)
     ctl = harness.reference_readings(cell["arch"], cell["job"], seed, precision="fp8")
-    assert check.gaps(ctl, ref)["grad"][0] >= 3 * check.gaps(prog, ref)["grad"][0]
+    stacked = weights.stacked(cell["arch"])
+    assert (check.gaps(ctl, ref, stacked)["grad"][0]
+            >= 3 * check.gaps(prog, ref, stacked)["grad"][0])

@@ -7,7 +7,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import hlo, work, xplane
+from bench import families, hlo, work, xplane
 from bench.harness import Run, read_metric
 
 CONFIGS = Path(__file__).with_name("configs")
@@ -119,10 +119,12 @@ def _arch(name):
 
 
 def test_model_flops_per_token():
-    assert work.matmul_params(_arch("smollm-135m")) == 134_479_872
-    assert work.model_flops_per_token(_arch("smollm-135m"), 2048) == pytest.approx(1.0192e9, rel=1e-4)
-    assert work.matmul_params(_arch("smollm-360m")) == 361_758_720
-    assert work.model_flops_per_token(_arch("smollm-360m"), 2048) == pytest.approx(2.548e9, rel=1e-3)
+    """The count ``step_mfu`` reads, through each configuration's family."""
+    a135, a360 = _arch("smollm-135m"), _arch("smollm-360m")
+    assert families.of(a135).matmul_params(a135) == 134_479_872
+    assert work.model_flops_per_token(a135, 2048) == pytest.approx(1.0192e9, rel=1e-4)
+    assert families.of(a360).matmul_params(a360) == 361_758_720
+    assert work.model_flops_per_token(a360, 2048) == pytest.approx(2.548e9, rel=1e-3)
 
 
 def test_flash_forward_work():

@@ -1,17 +1,20 @@
 """The work a step and a kernel call require, and the chip's peaks.
 
 These functions are the benchmark's yardstick: model FLOPs per trained
-token (for ``step_mfu``) and the FLOPs and bytes one call of a kernel
-requires (for ``<kernel>_roofline``).  They count the work the algorithm
-needs, not what an implementation happens to do: recomputation, masked
-blocks and re-reads are not counted, so a share computed from them cannot
-pass 100 % unless the time leaves out part of the work.
+token (for ``step_mfu``; each family counts its own block) and the FLOPs
+and bytes one call of a kernel requires (for ``<kernel>_roofline``).  They
+count the work the algorithm needs, not what an implementation happens to
+do: recomputation, masked blocks and re-reads are not counted, so a share
+computed from them cannot pass 100 % unless the time leaves out part of
+the work.
 """
 from __future__ import annotations
 
 import json
 import math
 from pathlib import Path
+
+from bench import families
 
 PEAKS = Path(__file__).with_name("peaks.json")
 
@@ -26,26 +29,10 @@ def peak(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def matmul_params(arch: dict) -> int:
-    """Parameters that enter a matrix multiplication once per token: every
-    attention projection, the MLP and the LM head (not the embedding
-    gather, not the norms)."""
-    d, f, l = arch["hidden_size"], arch["intermediate_size"], arch["num_hidden_layers"]
-    hd = arch.get("head_dim") or d // arch["num_attention_heads"]
-    attn = 2 * d * arch["num_attention_heads"] * hd + 2 * d * arch["num_key_value_heads"] * hd
-    mlp = 3 * d * f
-    return l * (attn + mlp) + d * arch["vocab_size"]
-
-
 def model_flops_per_token(arch: dict, seq: int) -> float:
-    """Forward and backward FLOPs one trained token requires: 6 per matmul
-    parameter, plus causal attention, 4 * (seq / 2) * heads * head_dim per
-    layer for the forward (QK^T and PV over the average causal span), three
-    times over for forward and backward."""
-    d = arch["hidden_size"]
-    hd = arch.get("head_dim") or d // arch["num_attention_heads"]
-    attn = 4 * (seq / 2) * arch["num_attention_heads"] * hd * arch["num_hidden_layers"]
-    return 6.0 * matmul_params(arch) + 3.0 * attn
+    """Forward and backward FLOPs one trained token requires, as the
+    configuration's family counts them (``bench/families/``)."""
+    return families.of(arch).model_flops_per_token(arch, seq)
 
 
 def flash_fwd_work(q_shape, k_shape, q_bytes: int, kv_bytes: int,

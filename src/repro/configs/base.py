@@ -24,6 +24,22 @@ class ModelConfig:
     top_k: int = 0
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
+    router: str = "softmax"         # softmax: GShard capacity dispatch |
+                                    # sigmoid: DeepSeek-V3 gate, dropless
+    routed_scaling: float = 1.0     # sigmoid gate: gates times this
+    norm_topk: bool = True          # sigmoid gate: gates over their sum
+    seq_aux_alpha: float = 0.0      # sigmoid gate: sequence balance loss
+    n_shared_experts: int = 0       # shared experts, one SwiGLU of
+                                    # n_shared_experts * d_ff_expert
+    expert_offset: int = 0          # the experts this chip holds:
+    n_experts_held: int = 0         # [offset, offset + held); 0 -> all
+    first_k_dense: int = 0          # leading dense layers (MLP d_ff_dense)
+    d_ff_dense: int = 0
+    # --- multi-head latent attention (DeepSeek-V2/V3) ---
+    kv_lora_rank: int = 0           # 0 -> grouped-query attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -47,7 +63,19 @@ class ModelConfig:
 
     @property
     def head_dim_(self) -> int:
+        """Width of a query and key head."""
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim or self.d_model // max(self.n_heads, 1)
+
+    @property
+    def v_head_dim_(self) -> int:
+        """Width of a value head (MLA's differs from the key's)."""
+        return self.v_head_dim if self.kv_lora_rank else self.head_dim_
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def padded_vocab(self) -> int:
@@ -68,17 +96,26 @@ class ModelConfig:
         """True if attention cost is quadratic and unbounded (no window/ssm)."""
         return self.family in ("dense", "moe", "encdec", "vlm") and self.window == 0
 
+    def _attn_params(self) -> int:
+        d, hd, H = self.d_model, self.head_dim_, self.n_heads
+        if self.kv_lora_rank:          # MLA: W_q, W_kv_a, its norm, W_kv_b, W_o
+            r, dv = self.kv_lora_rank, self.v_head_dim_
+            return (d * H * hd + d * (r + self.qk_rope_head_dim) + r
+                    + r * H * (self.qk_nope_head_dim + dv) + H * dv * d)
+        return d * (H * hd) * 2 + d * (self.n_kv_heads * hd) * 2
+
     def n_params(self) -> float:
         """Analytic parameter count (for roofline MODEL_FLOPS = 6·N·D)."""
-        d, hd = self.d_model, self.head_dim_
-        attn = d * (self.n_heads * hd) * 2 + d * (self.n_kv_heads * hd) * 2
+        d = self.d_model
+        attn = self._attn_params()
         if self.family in ("ssm",):
             attn = 0
         mlp = 3 * d * self.d_ff if self.d_ff else 0
         moe = 0
         if self.n_experts:
             mlp = 0
-            moe = self.n_experts * 3 * d * self.d_ff_expert + d * self.n_experts
+            moe = (self.n_experts * 3 * d * self.d_ff_expert + d * self.n_experts
+                   + 3 * d * self.n_shared_experts * self.d_ff_expert)
         ssm = 0
         if self.ssm_state:
             din = self.d_inner
@@ -91,7 +128,8 @@ class ModelConfig:
             n_shared = self.n_layers // max(self.attn_every, 1)
             shared = attn + 3 * d * self.d_ff
             return self.n_layers * ssm + shared + 2 * self.vocab * d + n_shared * 0
-        total = self.n_layers * per_layer
+        k = self.first_k_dense
+        total = (self.n_layers - k) * per_layer + k * (attn + 3 * d * self.d_ff_dense)
         if self.family == "encdec":
             total += self.n_enc_layers * (d * d * 4 + 2 * d * self.d_ff)   # enc blocks (GELU MLP)
             total += self.n_layers * (d * d * 4)                            # cross-attn
@@ -103,8 +141,8 @@ class ModelConfig:
         if not self.n_experts:
             return self.n_params()
         d = self.d_model
-        dense_part = self.n_params() - self.n_layers * self.n_experts * 3 * d * self.d_ff_expert
-        return float(dense_part + self.n_layers * self.top_k * 3 * d * self.d_ff_expert)
+        expert = 3 * d * self.d_ff_expert * (self.n_layers - self.first_k_dense)
+        return float(self.n_params() - self.n_experts * expert + self.top_k * expert)
 
     def reduced(self) -> "ModelConfig":
         """A tiny same-family config for CPU smoke tests."""
@@ -114,13 +152,21 @@ class ModelConfig:
             n_layers=min(self.n_layers, 4 if self.family != "hybrid" else 2 * max(self.attn_every, 1)),
             d_model=128,
             n_heads=min(self.n_heads, 4),
-            n_kv_heads=min(self.n_kv_heads, 2),
+            n_kv_heads=min(self.n_heads, 4) if self.kv_lora_rank else min(self.n_kv_heads, 2),
             head_dim=32,
             d_ff=256 if self.d_ff else 0,
             d_ff_expert=128 if self.d_ff_expert else 0,
             vocab=512,
             n_experts=min(self.n_experts, 4),
             top_k=min(self.top_k, 2),
+            n_experts_held=min(self.n_experts_held, 4),
+            expert_offset=0,
+            first_k_dense=min(self.first_k_dense, 1),
+            d_ff_dense=256 if self.d_ff_dense else 0,
+            kv_lora_rank=32 if self.kv_lora_rank else 0,
+            qk_nope_head_dim=16 if self.kv_lora_rank else 0,
+            qk_rope_head_dim=16 if self.kv_lora_rank else 0,
+            v_head_dim=16 if self.kv_lora_rank else 0,
             ssm_state=min(self.ssm_state, 16),
             ssm_headdim=32 if self.ssm_state else self.ssm_headdim,
             ssm_chunk=32,

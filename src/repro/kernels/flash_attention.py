@@ -8,7 +8,8 @@ BlockSpec VMEM tiling:
   (m, l, acc) in VMEM scratch; on the last k-step it writes the output block
   and the rows' logsumexp ``lse = m + log(l)`` (f32, ``(B, Hq, 1, Sq)``: one
   lane-dense row per head).  GQA is handled in the k/v index_map (kv head =
-  q head // group).
+  q head // group).  v (and so o, dO and dV) may be narrower than q and k
+  (multi-head latent attention: q, k 192 wide, v 128).
 
 Backward (FlashAttention-2): with ``D = rowsum(dO * O)``, every block pair
 recomputes ``P = exp(s - lse)`` from q and k (``s = q k^T * scale``) and
@@ -134,13 +135,14 @@ def flash_attention_fwd(q, k, v, *, kind: str = "causal", window: int = 0,
                         k_len: int | None = None, scale: float | None = None,
                         bq: int = 128, bk: int = 128,
                         interpret: bool = False):
-    """q: (B, Hq, Sq, d);  k, v: (B, Hkv, Sk, d) -> (o (B, Hq, Sq, d),
-    lse (B, Hq, 1, Sq) f32).
+    """q, k: (B, Hq, Sq, d), (B, Hkv, Sk, d);  v: (B, Hkv, Sk, dv) ->
+    (o (B, Hq, Sq, dv), lse (B, Hq, 1, Sq) f32).
 
     Shapes must be block-aligned (ops.py pads); GQA via Hq = g * Hkv.
     """
     B, Hq, Sq, d = q.shape
     _, Hkv, Sk, _ = k.shape
+    dv = v.shape[-1]
     g = Hq // Hkv
     scale = scale if scale is not None else d ** -0.5
     bq = min(bq, Sq)
@@ -159,18 +161,18 @@ def flash_attention_fwd(q, k, v, *, kind: str = "causal", window: int = 0,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j, g=g: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j, g=g: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, bk, dv), lambda b, h, i, j, g=g: (b, h // g, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((B, Hq, Sq, d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, Hq, Sq, dv), q.dtype),
                    jax.ShapeDtypeStruct((B, Hq, 1, Sq), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -329,6 +331,7 @@ def flash_bwd_dkv(q, k, v, do, lse, di, *, kind: str = "causal",
     ``lse``."""
     B, Hq, Sq, d = q.shape
     _, Hkv, Sk, _ = k.shape
+    dv = v.shape[-1]
     g = Hq // Hkv
     st = _bwd_setup(q, k, scale, k_len, bq, bk)
     bq, bk = st["bq"], st["bk"]
@@ -339,21 +342,28 @@ def flash_bwd_dkv(q, k, v, do, lse, di, *, kind: str = "causal",
                                      bk=bk, n_q=n_q))
 
     from jax.experimental.pallas import tpu as pltpu
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, j, gi, i:
-                          (b, h * g + gi, q_blk(j, i), 0))
+    def q_map(b, h, j, gi, i):
+        return (b, h * g + gi, q_blk(j, i), 0)
+
+    def kv_map(b, h, j, gi, i):
+        return (b, h, j, 0)
+
+    q_spec = pl.BlockSpec((1, 1, bq, d), q_map)
+    do_spec = pl.BlockSpec((1, 1, bq, dv), q_map)
     row_spec = pl.BlockSpec((1, 1, 1, bq), lambda b, h, j, gi, i:
                             (b, h * g + gi, 0, q_blk(j, i)))
-    kv_spec = pl.BlockSpec((1, 1, bk, d), lambda b, h, j, gi, i: (b, h, j, 0))
+    k_spec = pl.BlockSpec((1, 1, bk, d), kv_map)
+    v_spec = pl.BlockSpec((1, 1, bk, dv), kv_map)
     return pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, kind=kind, window=window,
                           **st),
         grid=(B, Hkv, Sk // bk, g, n_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                        pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
@@ -370,6 +380,7 @@ def flash_bwd_dq(q, k, v, do, lse, di, *, kind: str = "causal",
     sequential.  Operands as :func:`flash_bwd_dkv`'s."""
     B, Hq, Sq, d = q.shape
     _, Hkv, Sk, _ = k.shape
+    dv = v.shape[-1]
     g = Hq // Hkv
     st = _bwd_setup(q, k, scale, k_len, bq, bk)
     bq, bk = st["bq"], st["bk"]
@@ -380,15 +391,21 @@ def flash_bwd_dq(q, k, v, do, lse, di, *, kind: str = "causal",
                                      k_len=st["k_len"], bq=bq, bk=bk, n_k=n_k))
 
     from jax.experimental.pallas import tpu as pltpu
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0))
+    def q_map(b, h, i, j):
+        return (b, h, i, 0)
+
+    def kv_map(b, h, i, j):
+        return (b, h // g, k_blk(i, j), 0)
+
+    q_spec = pl.BlockSpec((1, 1, bq, d), q_map)
     row_spec = pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i))
-    kv_spec = pl.BlockSpec((1, 1, bk, d),
-                           lambda b, h, i, j: (b, h // g, k_blk(i, j), 0))
     return pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, kind=kind, window=window,
                           **st),
         grid=(B, Hq, Sq // bq, n_k),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, pl.BlockSpec((1, 1, bk, d), kv_map),
+                  pl.BlockSpec((1, 1, bk, dv), kv_map),
+                  pl.BlockSpec((1, 1, bq, dv), q_map), row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
@@ -406,7 +423,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, **kw):
     """Gradients (dq, dk, dv) of the forward's output ``o`` given its
     cotangent ``do``, from the forward's ``lse``.
 
-    q, o, do: (B, Hq, Sq, d);  k, v: (B, Hkv, Sk, d);  lse: (B, Hq, 1, Sq).
+    q: (B, Hq, Sq, d);  k: (B, Hkv, Sk, d);  v: (B, Hkv, Sk, dv);  o, do:
+    (B, Hq, Sq, dv);  lse: (B, Hq, 1, Sq).
     Block-aligned as the forward's.  Keywords as :func:`flash_bwd_dkv`'s;
     bq/bk default to :func:`bwd_blocks` of the lengths.
     """

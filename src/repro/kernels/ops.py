@@ -23,6 +23,7 @@ from repro.kernels.collective_reduce import collective_reduce as _cr_pallas
 from repro.kernels.flash_attention import (flash_attention_bwd,
                                            flash_attention_fwd)
 from repro.kernels.grouped_matmul import grouped_matmul as _gmm_pallas
+from repro.kernels.grouped_matmul import gmm_ragged, tgmm_ragged
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
@@ -179,6 +180,44 @@ tacc.register("expert_ffn", "interpret")(
     functools.partial(expert_ffn_pallas, interpret=True))
 tacc.register("grouped_matmul", "cpu", default=True)(ref.grouped_matmul)
 tacc.register("grouped_matmul", "tpu")(compat.manual_region(grouped_matmul))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _ragged_gmm(x, w, group_sizes, interpret):
+    """Ragged grouped matmul with a custom VJP: the forward and dX are
+    ``_gmm_ragged_kernel`` (dX with w transposed), dW is
+    ``_tgmm_ragged_kernel``; each runs in an all-manual region."""
+    return compat.manual_region(gmm_ragged)(x, w, group_sizes,
+                                            interpret=interpret)
+
+
+def _ragged_gmm_fwd(x, w, group_sizes, interpret):
+    return _ragged_gmm(x, w, group_sizes, interpret), (x, w, group_sizes)
+
+
+def _ragged_gmm_bwd(interpret, res, dy):
+    x, w, group_sizes = res
+    dx = compat.manual_region(gmm_ragged)(dy, w, group_sizes, transpose_rhs=True,
+                                          interpret=interpret)
+    dw = compat.manual_region(tgmm_ragged)(x, dy, group_sizes,
+                                           interpret=interpret)
+    return dx, dw, None
+
+
+_ragged_gmm.defvjp(_ragged_gmm_fwd, _ragged_gmm_bwd)
+
+
+def ragged_grouped_matmul(x, w, group_sizes, *, interpret=False):
+    """x (R, K) @ w[g] (G, K, N) over groups of rows laid out in order, each
+    starting on a multiple of ``grouped_matmul.ROW_TILE`` (``group_sizes``
+    are the padded sizes) -> (R, N); differentiable in x and w."""
+    return _ragged_gmm(x, w, group_sizes, interpret)
+
+
+tacc.register("ragged_grouped_matmul", "cpu", default=True)(ref.ragged_grouped_matmul)
+tacc.register("ragged_grouped_matmul", "tpu")(ragged_grouped_matmul)
+tacc.register("ragged_grouped_matmul", "interpret")(
+    functools.partial(ragged_grouped_matmul, interpret=True))
 
 
 # ---------------------------------------------------------------------------

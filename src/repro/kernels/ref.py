@@ -27,7 +27,7 @@ def attention(q, k, v, *, kind="causal", window=0, k_len=None, scale=None):
     s = jnp.where(valid[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqk,bhkd->bhgqd", p, v.astype(jnp.float32))
-    return o.reshape(B, Hq, Sq, d).astype(q.dtype)
+    return o.reshape(B, Hq, Sq, v.shape[-1]).astype(q.dtype)
 
 
 def grouped_matmul(x, w):
@@ -35,6 +35,13 @@ def grouped_matmul(x, w):
     out = jnp.einsum("gmk,gkn->gmn", x.astype(jnp.float32),
                      w.astype(jnp.float32))
     return out.astype(x.dtype)
+
+
+def ragged_grouped_matmul(x, w, group_sizes):
+    """x (R,K) @ w[g] (G,K,N) for the rows of each group, the groups laid
+    out in order from row 0 -> (R,N); rows past the last group are zero."""
+    return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def ssd_scan(x, dt, a_cum, B_in, C_in):

@@ -38,13 +38,14 @@ def chunked_attention(q, k, v, *, kind: str = "causal", window: int = 0,
                       scale: float | None = None):
     """Online-softmax attention over KV chunks.
 
-    q: (B, Sq, Hq, hd);  k, v: (B, Sk, Hkv, hd);  Hq % Hkv == 0.
-    q_offset/k_offset: global positions of q[0] / k[0] (cache decode uses
-    q_offset = cache_len).  k_len: valid KV prefix length (traced ok).
-    Returns (B, Sq, Hq, hd) in q.dtype.
+    q, k: (B, Sq, Hq, hd), (B, Sk, Hkv, hd);  v: (B, Sk, Hkv, dv);
+    Hq % Hkv == 0.  q_offset/k_offset: global positions of q[0] / k[0]
+    (cache decode uses q_offset = cache_len).  k_len: valid KV prefix length
+    (traced ok).  Returns (B, Sq, Hq, dv) in q.dtype.
     """
     B, Sq, Hq, hd = q.shape
     _, Sk, Hkv, _ = k.shape
+    dv = v.shape[-1]
     g = Hq // Hkv
     scale = scale if scale is not None else hd ** -0.5
     qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, Hkv, g, hd)
@@ -55,7 +56,7 @@ def chunked_attention(q, k, v, *, kind: str = "causal", window: int = 0,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kc = k.reshape(B, n_chunks, chunk, Hkv, hd)
-    vc = v.reshape(B, n_chunks, chunk, Hkv, hd)
+    vc = v.reshape(B, n_chunks, chunk, Hkv, dv)
     q_pos = q_offset + jnp.arange(Sq)
     kv_valid_len = jnp.asarray(Sk if k_len is None else k_len)
 
@@ -77,15 +78,15 @@ def chunked_attention(q, k, v, *, kind: str = "causal", window: int = 0,
     init = (
         jnp.full((B, Hkv, g, Sq), NEG_INF, jnp.float32),
         jnp.zeros((B, Hkv, g, Sq), jnp.float32),
-        jnp.zeros((B, Hkv, g, Sq, hd), jnp.float32),
+        jnp.zeros((B, Hkv, g, Sq, dv), jnp.float32),
     )
     kc_t = jnp.moveaxis(kc, 1, 0)
     vc_t = jnp.moveaxis(vc, 1, 0)
     body_ckpt = jax.checkpoint(body)
     (m, l, acc), _ = jax.lax.scan(
         body_ckpt, init, (kc_t, vc_t, jnp.arange(n_chunks)))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]          # (B, Hkv, g, Sq, hd)
-    out = jnp.moveaxis(out, 3, 1).reshape(B, Sq, Hq, hd)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]          # (B, Hkv, g, Sq, dv)
+    out = jnp.moveaxis(out, 3, 1).reshape(B, Sq, Hq, dv)
     return out.astype(q.dtype)
 
 
@@ -111,7 +112,7 @@ def dense_reference(q, k, v, *, kind="causal", window=0, q_offset=0,
     s = jnp.where(valid[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bhgqd", p, v.astype(jnp.float32))
-    return jnp.moveaxis(out, 3, 1).reshape(B, Sq, Hq, hd).astype(q.dtype)
+    return jnp.moveaxis(out, 3, 1).reshape(B, Sq, Hq, v.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

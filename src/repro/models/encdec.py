@@ -133,7 +133,7 @@ def decode_train(params, tokens, enc_out, cfg: ModelConfig, ctx: Ctx):
 
 def forward(params, batch, cfg: ModelConfig, ctx: Ctx):
     enc_out = encode(params, batch["frames"], cfg, ctx)
-    return decode_train(params, batch["tokens"], enc_out, cfg, ctx), jnp.zeros((), jnp.float32)
+    return decode_train(params, batch["tokens"], enc_out, cfg, ctx), {"loss": jnp.zeros((), jnp.float32)}
 
 
 def prefill(params, batch, cfg: ModelConfig, ctx: Ctx,

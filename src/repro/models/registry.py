@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import encdec as encdec_mod
+from repro.models import moe as moe_mod
 from repro.models import transformer as tf
 from repro.models.common import ParamMeta, init_params, shape_tree, spec_tree
 
@@ -63,7 +64,9 @@ class Model:
         return {**params, **gathered}
 
     def loss(self, params, batch, ctx: tf.Ctx):
-        """Returns (sum of token CE losses, token count, aux scalar)."""
+        """Returns (sum of token CE losses, token count, aux): aux["loss"]
+        is the auxiliary loss per token, beside it the counters that
+        :meth:`counters` names."""
         cfg = self.cfg
         params = self._gather_top(params, ctx)
         if cfg.family == "encdec":
@@ -77,6 +80,12 @@ class Model:
         loss_sum, count = tf.lm_loss_from_hidden(params, hidden, batch["labels"],
                                                  mask, cfg, ctx)
         return loss_sum, count, aux
+
+    def counters(self) -> tuple[str, ...]:
+        """Names of the counters the forward reports beside its auxiliary
+        loss (the DeepSeek-V3 expert layer's; none for other blocks)."""
+        cfg = self.cfg
+        return tuple(moe_mod.COUNTERS) if cfg.router == "sigmoid" and cfg.n_experts else ()
 
     # ---- serving ----------------------------------------------------------
     def prefill(self, params, batch, ctx: tf.Ctx, max_len: int | None = None):

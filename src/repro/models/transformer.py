@@ -31,11 +31,15 @@ from repro.models.common import (ParamMeta, apply_rope, embed_lookup, is_meta,
 
 # Names of the model's layers in the compiled program (DESIGN.md §16): each
 # op a layer emits, forward, recompute and (through the transpose) backward,
-# carries its scope in its HLO op_name, where a device trace finds it.
+# carries its scope in its HLO op_name, where a device trace finds it.  The
+# DeepSeek-V3 expert layer adds its sub-scopes under ``mlp`` (repro.models.moe).
 ATTENTION_SCOPE = "attention"
 MLP_SCOPE = "mlp"
 LM_HEAD_SCOPE = "lm_head"
 EMBED_SCOPE = "embed"
+# RMSNorm epsilon of MLA's compressed kv (HF DeepseekV3RMSNorm's default; the
+# published config does not set it).
+MLA_KV_NORM_EPS = 1e-6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,11 +129,28 @@ def gather_plan_of(metas, rules, scanned: bool):
 # Parameter metadata
 # ---------------------------------------------------------------------------
 
+def _mla_metas(cfg: ModelConfig, pre: tuple, pax: tuple) -> dict:
+    """Multi-head latent attention (DeepSeek-V2/V3, no q LoRA): W_q (D -> H x
+    (nope + rope)), W_kv_a (D -> kv_lora_rank + rope), the latent's RMSNorm,
+    W_kv_b (kv_lora_rank -> H x (nope + v)), W_o (H x v -> D)."""
+    D, H, R = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim_
+    return {
+        "wq": ParamMeta(pre + (D, H, dn + dr), pax + ("embed", "q_heads", "head")),
+        "wkv_a": ParamMeta(pre + (D, R + dr), pax + ("embed", "latent")),
+        "kv_norm": ParamMeta(pre + (R,), pax + ("latent",), "ones"),
+        "wkv_b": ParamMeta(pre + (R, H, dn + dv), pax + ("latent", "q_heads", "head")),
+        "wo": ParamMeta(pre + (H, dv, D), pax + ("q_heads", "head", "embed")),
+    }
+
+
 def _attn_metas(cfg: ModelConfig, L_axis: str = "layers", L: int | None = None,
                 bias: bool = False) -> dict:
     D, Hq, Hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     pre = (L,) if L else ()
     pax = (L_axis,) if L else ()
+    if cfg.kv_lora_rank:
+        return _mla_metas(cfg, pre, pax)
     m = {
         "wq": ParamMeta(pre + (D, Hq, hd), pax + ("embed", "q_heads", "head")),
         "wk": ParamMeta(pre + (D, Hkv, hd), pax + ("embed", "kv_heads", "head")),
@@ -144,8 +165,9 @@ def _attn_metas(cfg: ModelConfig, L_axis: str = "layers", L: int | None = None,
 
 
 def _mlp_metas(cfg: ModelConfig, L: int | None = None, gated: bool = True,
-               bias: bool = False, L_axis: str = "layers") -> dict:
-    D, F = cfg.d_model, cfg.d_ff
+               bias: bool = False, L_axis: str = "layers",
+               F: int | None = None) -> dict:
+    D, F = cfg.d_model, F or cfg.d_ff
     pre = (L,) if L else ()
     pax = (L_axis,) if L else ()
     m = {
@@ -161,13 +183,19 @@ def _mlp_metas(cfg: ModelConfig, L: int | None = None, gated: bool = True,
 
 
 def _moe_metas(cfg: ModelConfig, L: int) -> dict:
+    """The router over all ``n_experts``, the held experts' weights, and
+    the shared experts as one SwiGLU."""
     D, E, F = cfg.d_model, cfg.n_experts, cfg.d_ff_expert
-    return {
+    Eh = cfg.experts_held
+    m = {
         "router": ParamMeta((L, D, E), ("layers", "embed", "experts")),
-        "w1": ParamMeta((L, E, D, F), ("layers", "experts", "embed", "expert_mlp")),
-        "w3": ParamMeta((L, E, D, F), ("layers", "experts", "embed", "expert_mlp")),
-        "w2": ParamMeta((L, E, F, D), ("layers", "experts", "expert_mlp", "embed")),
+        "w1": ParamMeta((L, Eh, D, F), ("layers", "experts", "embed", "expert_mlp")),
+        "w3": ParamMeta((L, Eh, D, F), ("layers", "experts", "embed", "expert_mlp")),
+        "w2": ParamMeta((L, Eh, F, D), ("layers", "experts", "expert_mlp", "embed")),
     }
+    if cfg.n_shared_experts:
+        m["shared"] = _mlp_metas(cfg, L=L, F=cfg.n_shared_experts * F)
+    return m
 
 
 def _ssm_metas(cfg: ModelConfig, L: int, L_axes: tuple[str, ...] = ("layers",)) -> dict:
@@ -210,12 +238,20 @@ def abstract_params(cfg: ModelConfig) -> dict:
             "mlp": _mlp_metas(cfg, L=L),
         }
     elif cfg.family == "moe":
+        Lm = L - cfg.first_k_dense
         base["blocks"] = {
-            "ln1": ParamMeta((L, D), ("layers", "embed"), "ones"),
-            "ln2": ParamMeta((L, D), ("layers", "embed"), "ones"),
-            "attn": _attn_metas(cfg, L=L),
-            "moe": _moe_metas(cfg, L),
+            "ln1": ParamMeta((Lm, D), ("layers", "embed"), "ones"),
+            "ln2": ParamMeta((Lm, D), ("layers", "embed"), "ones"),
+            "attn": _attn_metas(cfg, L=Lm),
+            "moe": _moe_metas(cfg, Lm),
         }
+        if cfg.first_k_dense:      # leading dense layers, unstacked
+            base["dense"] = {str(i): {
+                "ln1": ParamMeta((D,), ("embed",), "ones"),
+                "ln2": ParamMeta((D,), ("embed",), "ones"),
+                "attn": _attn_metas(cfg),
+                "mlp": _mlp_metas(cfg, F=cfg.d_ff_dense),
+            } for i in range(cfg.first_k_dense)}
     elif cfg.family == "ssm":
         base["blocks"] = _ssm_metas(cfg, L)
     elif cfg.family == "hybrid":
@@ -239,7 +275,28 @@ def abstract_params(cfg: ModelConfig) -> dict:
 # Blocks
 # ---------------------------------------------------------------------------
 
+def _mla_qkv(p, x, positions, cfg: ModelConfig):
+    """MLA's q, k, v, computed unabsorbed: q (B,S,H,nope+rope) from W_q;
+    the latent ``c`` and the one-head ``k_pe`` from W_kv_a; ``c`` normed,
+    then k_nope and v from W_kv_b; RoPE on q_pe and k_pe; k_pe broadcast
+    over the heads."""
+    dn, R = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+    ckv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(x.dtype))
+    c = rms_norm(ckv[..., :R], p["kv_norm"].astype(jnp.float32), MLA_KV_NORM_EPS)
+    kv = jnp.einsum("bsr,rhk->bshk", c, p["wkv_b"].astype(x.dtype))
+    q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    k_pe = apply_rope(ckv[..., None, R:], positions, cfg.rope_theta)
+    H = q.shape[2]
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        k_pe, k_pe.shape[:2] + (H, k_pe.shape[-1]))], axis=-1)
+    return q, k, kv[..., dn:]
+
+
 def _qkv(p, x, positions, cfg: ModelConfig, ctx: Ctx):
+    if "wkv_a" in p:
+        return _mla_qkv(p, x, positions, cfg)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(x.dtype))
@@ -301,34 +358,53 @@ def mlp_sublayer(p, h, cfg: ModelConfig, ctx: Ctx):
         return out
 
 
+def ffn_sublayer(p, h2, cfg: ModelConfig, ctx: Ctx):
+    """The block's feed-forward over pre-normed ``h2``: the dense MLP, or
+    the expert layer.  Returns (output, aux)."""
+    if "moe" not in p:
+        return mlp_sublayer(p["mlp"], h2, cfg, ctx), {}
+    B, S, D = h2.shape
+    # Resolve any pending partial-sum sharding BEFORE dispatch: without
+    # this XLA defers the attention-output psum past the token gather and
+    # all-reduces the top_k-times-larger (T*k, D) matrix (measured 6x
+    # wire inflation on moonshot — see EXPERIMENTS.md §Perf).
+    if not ctx.rules.get("_attn_sp"):
+        h2 = ctx.wsc(h2, "batch", None, None)
+    if cfg.router == "sigmoid":
+        with jax.named_scope(MLP_SCOPE):
+            out, aux = moe_mod.deepseek_moe(
+                h2.reshape(B * S, D), p["moe"], n_seqs=B, top_k=cfg.top_k,
+                expert_offset=cfg.expert_offset, n_held=cfg.experts_held,
+                routed_scaling=cfg.routed_scaling, norm_topk=cfg.norm_topk,
+                seq_aux_alpha=cfg.seq_aux_alpha)
+        return out.reshape(B, S, D), aux
+    # Buffer-replication pins are a train-context (manual DP) move only:
+    # under pjit serving the token dim is batch-sharded over (pod, data)
+    # and pinning the dispatch buffer replicated would gather the whole
+    # batch across the fleet (measured 3x prefill regression).
+    out, aux = moe_mod.moe_ffn(h2.reshape(B * S, D), p["moe"],
+                               n_experts=cfg.n_experts, top_k=cfg.top_k,
+                               capacity_factor=cfg.capacity_factor,
+                               expert_axis=ctx.rules.get("experts"),
+                               replicate_buffers=ctx.manual)
+    return out.reshape(B, S, D), aux
+
+
 def dense_block(p, x, positions, cfg, ctx, cache=None, pos=None):
     h = rms_norm(x, p["ln1"].astype(jnp.float32), cfg.norm_eps)
     a, new_cache = attn_sublayer(p["attn"], h, positions, cfg, ctx,
                                  cache=cache, pos=pos)
     x = x + a
     h2 = rms_norm(x, p["ln2"].astype(jnp.float32), cfg.norm_eps)
-    if "moe" in p:
-        B, S, D = h2.shape
-        # Resolve any pending partial-sum sharding BEFORE dispatch: without
-        # this XLA defers the attention-output psum past the token gather and
-        # all-reduces the top_k-times-larger (T*k, D) matrix (measured 6x
-        # wire inflation on moonshot — see EXPERIMENTS.md §Perf).
-        if not ctx.rules.get("_attn_sp"):
-            h2 = ctx.wsc(h2, "batch", None, None)
-        # Buffer-replication pins are a train-context (manual DP) move only:
-        # under pjit serving the token dim is batch-sharded over (pod, data)
-        # and pinning the dispatch buffer replicated would gather the whole
-        # batch across the fleet (measured 3x prefill regression).
-        out, aux = moe_mod.moe_ffn(h2.reshape(B * S, D), p["moe"],
-                                   n_experts=cfg.n_experts, top_k=cfg.top_k,
-                                   capacity_factor=cfg.capacity_factor,
-                                   expert_axis=ctx.rules.get("experts"),
-                                   replicate_buffers=ctx.manual)
-        x = x + out.reshape(B, S, D)
-    else:
-        aux = {}
-        x = x + mlp_sublayer(p["mlp"], h2, cfg, ctx)
-    return x, new_cache, aux
+    out, aux = ffn_sublayer(p, h2, cfg, ctx)
+    return x + out, new_cache, aux
+
+
+def _aux_loss(a: dict):
+    """One layer's auxiliary loss: the softmax router's weighted switch and
+    z losses, or the sigmoid router's balance loss."""
+    return (a.get("moe_aux", 0.0) * 0.01 + a.get("moe_z", 0.0) * 1e-3
+            + a.get("moe_balance", 0.0))
 
 
 def ssm_block(p, x, cfg: ModelConfig, ctx: Ctx, state=None, conv=None):
@@ -427,7 +503,8 @@ def _blocks_gplan(cfg: ModelConfig, rules):
 
 def forward_lm(params, tokens, cfg: ModelConfig, ctx: Ctx, *, mrope=None,
                return_kv: bool = False):
-    """Token ids -> final hidden states (B,S,D) (+ aux losses, + per-layer kv).
+    """Token ids -> (final hidden states (B,S,D), aux): aux["loss"] is the
+    auxiliary loss per token, beside it the expert layer's counters.
 
     Families: dense | moe | vlm (dense_block), ssm (ssm_block),
     hybrid (grouped ssm + shared attention).
@@ -440,16 +517,25 @@ def forward_lm(params, tokens, cfg: ModelConfig, ctx: Ctx, *, mrope=None,
     gplans = _blocks_gplan(cfg, ctx.rules) if ctx.manual else None
 
     if cfg.family in ("dense", "moe", "vlm"):
-        def body_simple(carry, layer_p):
+        def layer(carry, layer_p):
+            # the expert counters leave as scan outputs, not through the
+            # differentiated carry
             h, aux = carry
+            h, _, a = dense_block(layer_p, h, positions, cfg, ctx)
+            return (h, aux + _aux_loss(a)), {k: a[k] for k in moe_mod.COUNTERS if k in a}
+
+        def body_simple(carry, layer_p):
             if gplans is not None:
                 layer_p = maybe_gather(layer_p, gplans["blocks"])
-            h, _, a = dense_block(layer_p, h, positions, cfg, ctx)
-            aux = aux + a.get("moe_aux", 0.0) * 0.01 + a.get("moe_z", 0.0) * 1e-3
-            return (h, aux), None
-        (x, aux), _ = jax.lax.scan(jax.checkpoint(body_simple),
-                                   (x, jnp.zeros((), jnp.float32)),
-                                   params["blocks"])
+            return layer(carry, layer_p)
+        carry = (x, jnp.zeros((), jnp.float32))
+        # the leading dense layers, unstacked (top-level params: ZeRO-3
+        # gathers them whole with the embedding and the head)
+        for i in range(cfg.first_k_dense):
+            carry, _ = jax.checkpoint(layer)(carry, params["dense"][str(i)])
+        (x, aux_loss), counters = jax.lax.scan(jax.checkpoint(body_simple), carry,
+                                               params["blocks"])
+        aux = {"loss": aux_loss, **moe_mod.reduce_counters(counters)}
     elif cfg.family == "ssm":
         def body(h, layer_p):
             if gplans is not None:
@@ -457,7 +543,7 @@ def forward_lm(params, tokens, cfg: ModelConfig, ctx: Ctx, *, mrope=None,
             h, _ = ssm_block(layer_p, h, cfg, ctx)
             return h, None
         x, _ = jax.lax.scan(jax.checkpoint(body), x, params["blocks"])
-        aux = jnp.zeros((), jnp.float32)
+        aux = {"loss": jnp.zeros((), jnp.float32)}
     elif cfg.family == "hybrid":
         shared = params["shared"]
         if gplans is not None:
@@ -491,7 +577,7 @@ def forward_lm(params, tokens, cfg: ModelConfig, ctx: Ctx, *, mrope=None,
                     h, _ = ssm_block(lp, h, cfg, ctx)
                     return h, None
             x, _ = jax.lax.scan(jax.checkpoint(tail_body), x, tail_p)
-        aux = jnp.zeros((), jnp.float32)
+        aux = {"loss": jnp.zeros((), jnp.float32)}
     else:
         raise ValueError(cfg.family)
 
@@ -553,13 +639,16 @@ def cache_metas(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     hd = cfg.head_dim_
     if cfg.family in ("dense", "moe", "vlm", "encdec"):
         S = min(max_len, cfg.window) if cfg.window else max_len
-        c = {
-            "k": ParamMeta((cfg.n_layers, batch, S, cfg.n_kv_heads, hd),
-                           ("layers", "cbatch", "cseq", "kv_heads", "head"), "zeros"),
-            "v": ParamMeta((cfg.n_layers, batch, S, cfg.n_kv_heads, hd),
-                           ("layers", "cbatch", "cseq", "kv_heads", "head"), "zeros"),
-            "pos": ParamMeta((), (), "zeros"),
-        }
+
+        def kv(n, width):
+            return ParamMeta((n, batch, S, cfg.n_kv_heads, width),
+                             ("layers", "cbatch", "cseq", "kv_heads", "head"), "zeros")
+        n = cfg.n_layers - cfg.first_k_dense
+        c = {"k": kv(n, hd), "v": kv(n, cfg.v_head_dim_),
+             "pos": ParamMeta((), (), "zeros")}
+        if cfg.first_k_dense:      # the leading dense layers' own
+            c["dense_k"] = kv(cfg.first_k_dense, hd)
+            c["dense_v"] = kv(cfg.first_k_dense, cfg.v_head_dim_)
         if cfg.family == "encdec":
             c["cross_k"] = ParamMeta(
                 (cfg.n_layers, batch, cfg.n_frames, cfg.n_kv_heads, hd),
@@ -617,8 +706,17 @@ def decode_lm(params, cache, tokens, cfg: ModelConfig, ctx: Ctx):
             h, new_c, _ = dense_block(layer_p, h, positions, cfg, ctx,
                                       cache=(ck, cv), pos=pos)
             return h, new_c
+        new_cache = {"pos": pos + 1}
+        if cfg.first_k_dense:
+            dense = []
+            for i in range(cfg.first_k_dense):
+                x, kv = body(x, (params["dense"][str(i)], cache["dense_k"][i],
+                                 cache["dense_v"][i]))
+                dense.append(kv)
+            new_cache["dense_k"] = jnp.stack([kv[0] for kv in dense])
+            new_cache["dense_v"] = jnp.stack([kv[1] for kv in dense])
         x, (nk, nv) = jax.lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
-        new_cache = {"k": nk, "v": nv, "pos": pos + 1}
+        new_cache.update(k=nk, v=nv)
     elif cfg.family == "ssm":
         def body(h, inp):
             layer_p, st = inp
@@ -692,15 +790,7 @@ def prefill_lm(params, tokens, cfg: ModelConfig, ctx: Ctx, *, mrope=None,
             a = jnp.einsum("bshk,hkd->bsd", out, layer_p["attn"]["wo"].astype(h.dtype))
             h = h + a
             h2 = rms_norm(h, layer_p["ln2"].astype(jnp.float32), cfg.norm_eps)
-            if "moe" in layer_p:
-                o, _ = moe_mod.moe_ffn(h2.reshape(B * S, -1), layer_p["moe"],
-                                       n_experts=cfg.n_experts, top_k=cfg.top_k,
-                                       capacity_factor=cfg.capacity_factor,
-                                       expert_axis=ctx.rules.get("experts"),
-                                       replicate_buffers=ctx.manual)
-                h = h + o.reshape(B, S, -1)
-            else:
-                h = h + mlp_sublayer(layer_p["mlp"], h2, cfg, ctx)
+            h = h + ffn_sublayer(layer_p, h2, cfg, ctx)[0]
             if cfg.window and Sc == cfg.window:
                 # rolling cache: scatter last W positions at slot = pos % W
                 last = jnp.arange(S - Sc, S)
@@ -713,8 +803,16 @@ def prefill_lm(params, tokens, cfg: ModelConfig, ctx: Ctx, *, mrope=None,
                 cv = jnp.pad(v.astype(dtype), widths)
             return h, (ck, cv)
 
+        cache = {"pos": jnp.asarray(S, jnp.int32)}
+        if cfg.first_k_dense:
+            dense = []
+            for i in range(cfg.first_k_dense):
+                x, kv = body(x, params["dense"][str(i)])
+                dense.append(kv)
+            cache["dense_k"] = jnp.stack([kv[0] for kv in dense])
+            cache["dense_v"] = jnp.stack([kv[1] for kv in dense])
         x, (ks, vs) = jax.lax.scan(jax.checkpoint(body), x, params["blocks"])
-        cache = {"k": ks, "v": vs, "pos": jnp.asarray(S, jnp.int32)}
+        cache.update(k=ks, v=vs)
     elif cfg.family == "ssm":
         def body(h, layer_p):
             h, s, conv = ssm_prefill_block(layer_p, h, cfg, ctx)

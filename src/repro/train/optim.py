@@ -166,11 +166,13 @@ def zero1_master_from_params(params, dp_axes):
     return jax.tree.map(one, params)
 
 
-def zero1_step(params, grads, opt, step, rc: RunConfig, cfg):
+def zero1_step(params, grads, opt, step, rc: RunConfig, cfg, decay_mask):
     """Full ZeRO-1 step.  grads: full (un-reduced local sums); returns
     (new_params, new_opt).  Collectives: HetCCL AllReduce + AllGather.
     ``cfg``: the program's ``repro.comm.Communicator`` (or a legacy
-    ``HetCCLConfig``) — every collective resolves its policy from it."""
+    ``HetCCLConfig``) — every collective resolves its policy from it.
+    ``decay_mask``: per leaf, whether weight decay applies (matrices, not
+    norm scales or biases)."""
     with jax.named_scope(SCOPE):
         rank, world = dp_rank_and_world(cfg.dp_axes())
         ef = opt.get("ef")
@@ -181,14 +183,14 @@ def zero1_step(params, grads, opt, step, rc: RunConfig, cfg):
         gnorm = global_norm(grads)
         scale = clip_scale(gnorm, rc.grad_clip)
 
-        def one(p, g, m, v, master):
+        def one(p, g, m, v, master, decays):
             flat = g.reshape(-1).astype(jnp.float32) * scale
             pad = _pad_len(flat.size, world) - flat.size
             if pad:
                 flat = jnp.pad(flat, (0, pad))
             shard = flat.size // world
             g_sh = lax.dynamic_slice(flat, (rank * shard,), (shard,))
-            decay = 0.0 if p.ndim <= 1 else 1.0     # no decay on norms/biases
+            decay = 1.0 if decays else 0.0
             new_master, m, v = adam_update(g_sh, m, v, master, step, rc, decay)
             # parameter AllGather (the ZeRO-1 optimizer-state gather, Table 3)
             full = hetccl.all_gather(new_master.astype(p.dtype), cfg, dim=0)
@@ -200,8 +202,9 @@ def zero1_step(params, grads, opt, step, rc: RunConfig, cfg):
         leaves_m = tdef.flatten_up_to(opt["m"])
         leaves_v = tdef.flatten_up_to(opt["v"])
         leaves_ms = tdef.flatten_up_to(opt["master"])
-        out = [one(p, g, m, v, ms) for p, g, m, v, ms in
-               zip(leaves_p, leaves_g, leaves_m, leaves_v, leaves_ms)]
+        leaves_d = tdef.flatten_up_to(decay_mask)
+        out = [one(*leaf) for leaf in
+               zip(leaves_p, leaves_g, leaves_m, leaves_v, leaves_ms, leaves_d)]
         new_p = tdef.unflatten([o[0] for o in out])
         new_opt = {"m": tdef.unflatten([o[1] for o in out]),
                    "v": tdef.unflatten([o[2] for o in out]),
@@ -223,7 +226,8 @@ def zero3_init_opt(params):
             "master": jax.tree.map(lambda p: p.astype(jnp.float32), params)}
 
 
-def zero3_step(params, grads, opt, step, rc: RunConfig, cfg, fsdp_leaf_mask):
+def zero3_step(params, grads, opt, step, rc: RunConfig, cfg, fsdp_leaf_mask,
+               decay_mask):
     """grads: fsdp leaves already reduce-scattered over 'data' (the
     fsdp_all_gather adjoint); remaining reduction:
       fsdp leaves      -> AllReduce over 'pod' only (HetCCL cross stage),
@@ -248,13 +252,13 @@ def zero3_step(params, grads, opt, step, rc: RunConfig, cfg, fsdp_leaf_mask):
         gnorm = global_norm_sharded(grads, fsdp_leaf_mask, cfg)
         scale = clip_scale(gnorm, rc.grad_clip)
 
-        def one(p, g, m, v, master):
-            decay = 0.0 if p.ndim <= 1 else 1.0
+        def one(p, g, m, v, master, decays):
             new_master, m, v = adam_update(g.astype(jnp.float32) * scale, m, v,
-                                           master, step, rc, decay)
+                                           master, step, rc, 1.0 if decays else 0.0)
             return new_master.astype(p.dtype), m, v, new_master
 
-        flat = jax.tree.map(one, params, grads, opt["m"], opt["v"], opt["master"])
+        flat = jax.tree.map(one, params, grads, opt["m"], opt["v"], opt["master"],
+                            decay_mask)
         new_p = jax.tree.map(lambda o: o[0], flat, is_leaf=lambda x: isinstance(x, tuple))
         new_opt = {"m": jax.tree.map(lambda o: o[1], flat, is_leaf=lambda x: isinstance(x, tuple)),
                    "v": jax.tree.map(lambda o: o[2], flat, is_leaf=lambda x: isinstance(x, tuple)),

@@ -29,7 +29,8 @@ from repro.configs.base import ModelConfig, RunConfig
 from repro.core import compat, hetccl
 from repro.core.balance import HetPlan
 from repro.models import Ctx, Model
-from repro.models.common import make_rules, manual_only, spec_tree
+from repro.models import moe as moe_mod
+from repro.models.common import is_meta, make_rules, manual_only, spec_tree
 from repro.train import optim
 
 # Name of the step's token-count and loss reductions in the compiled program
@@ -160,6 +161,8 @@ def make_train_program(model: Model, mesh, rc: RunConfig, plan: HetPlan,
                       for e in s), pspecs)
     dp_world = int(np.prod([dict(zip(mesh.axis_names, mesh.devices.shape))[a]
                             for a in manual_axes]))
+    decay_mask = jax.tree.map(_decays, metas, is_leaf=is_meta)
+    counter_names = model.counters()
     live_mask = jnp.asarray(plan.live_mask())          # (n_pods, n_micro_max)
 
     # ---- the shard_map body -------------------------------------------------
@@ -170,38 +173,49 @@ def make_train_program(model: Model, mesh, rc: RunConfig, plan: HetPlan,
 
         def loss_fn(p, mb, w):
             loss_sum, count, aux = model.loss(p, mb, ctx)
-            objective = (loss_sum + aux * count) * w
-            return objective, (loss_sum * w, count * w)
+            objective = (loss_sum + aux["loss"] * count) * w
+            counters = {k: jnp.where(w > 0, aux[k], 0) for k in counter_names}
+            return objective, (loss_sum * w, count * w, counters)
 
         def micro(carry, inp):
             g_acc, l_acc, c_acc = carry
             mb, w = inp
-            (_, (ls, cnt)), g = jax.value_and_grad(loss_fn, has_aux=True)(
+            (_, (ls, cnt, n)), g = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, mb, w)
             g_acc = jax.tree.map(jnp.add, g_acc, g)
-            return (g_acc, l_acc + ls, c_acc + cnt), None
+            return (g_acc, l_acc + ls, c_acc + cnt), n
 
         g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
         mb_tree = {k: batch[k] for k in
                    ("tokens", "labels", *extra_batch_specs) if k in batch}
-        (grads, loss_sum, count), _ = lax.scan(
-            micro, (g0, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-            (mb_tree, live))
+        init = (g0, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
+        # A lone micro-step is called, not scanned: through a length-1 scan
+        # XLA's loop-invariant code motion leaves the layer loops inside it
+        # twice in the compiled step (tests/test_chip_compile.py).
+        if plan.n_micro_max == 1:
+            (grads, loss_sum, count), counters = micro(
+                init, jax.tree.map(lambda a: a[0], (mb_tree, live)))
+            counters = jax.tree.map(lambda a: a[None], counters)
+        else:
+            (grads, loss_sum, count), counters = lax.scan(micro, init, (mb_tree, live))
+        counters = moe_mod.reduce_counters(counters)
 
         with jax.named_scope(STEP_STATS_SCOPE):
             total_tokens = lax.psum(count, manual_axes)
             loss_total = lax.psum(loss_sum, manual_axes)
+            counters = {k: (lax.psum if moe_mod.COUNTERS[k] == "sum" else lax.pmax)(
+                v, manual_axes) for k, v in counters.items()}
         inv = 1.0 / jnp.maximum(total_tokens, 1.0)
         grads = jax.tree.map(lambda g: g * inv, grads)
 
         if rc.zero_stage >= 3:
             new_params, new_opt, gnorm = optim.zero3_step(
-                params, grads, opt, step, rc, comm, fsdp_mask)
+                params, grads, opt, step, rc, comm, fsdp_mask, decay_mask)
         else:
             new_params, new_opt, gnorm = optim.zero1_step(
-                params, grads, opt, step, rc, comm)
+                params, grads, opt, step, rc, comm, decay_mask)
         metrics = {"loss": loss_total * inv, "grad_norm": gnorm,
-                   "tokens": total_tokens}
+                   "tokens": total_tokens, **counters}
         return ({"params": new_params, "opt": new_opt, "step": step + 1}, metrics)
 
     # ---- specs --------------------------------------------------------------
@@ -211,7 +225,8 @@ def make_train_program(model: Model, mesh, rc: RunConfig, plan: HetPlan,
     batch_manual = P(None, manual_axes if len(manual_axes) > 1 else manual_axes[0], None)
     batch_spec_tree = {"tokens": batch_manual, "labels": batch_manual,
                        **extra_batch_specs}
-    metric_specs = {"loss": P(), "grad_norm": P(), "tokens": P()}
+    metric_specs = {"loss": P(), "grad_norm": P(), "tokens": P(),
+                    **{k: P() for k in counter_names}}
 
     def step_body_installed(state, batch):
         # hetccl.current() must reflect this program's communicator while
@@ -290,6 +305,13 @@ def rebuild_program(prog: TrainProgram, mesh, rc: RunConfig | None = None,
     return make_train_program(prog.model, mesh, rc or prog.rc,
                               plan or prog.plan,
                               extra_batch_specs=extra_batch_specs)
+
+
+def _decays(meta) -> bool:
+    """Weight decay applies to matrices: a leaf with more than one axis
+    besides its stacked layer axes (norm scales, biases and other vectors
+    are not decayed, stacked or not)."""
+    return sum(a not in ("layers", "group") for a in meta.axes) > 1
 
 
 def _opt_specs(rc: RunConfig, pspecs, manual_axes):

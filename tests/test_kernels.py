@@ -369,3 +369,83 @@ def test_wire_quant_ragged_shapes(shape):
     ec = np.pad(err.reshape(-1), (0, pad)).reshape(xc.shape)
     bound = np.abs(xc).max(axis=1) / 254.0
     assert (ec.max(axis=1) <= bound + 1e-7).all()
+
+
+def _ragged_rows(sizes, K, dtype, spare_tiles=1):
+    """x (R, K) holding groups of ``sizes`` rows, each starting on a
+    multiple of ROW_TILE, zero elsewhere; and the padded group sizes."""
+    from repro.kernels.grouped_matmul import ROW_TILE
+    padded = -(-np.asarray(sizes) // ROW_TILE) * ROW_TILE
+    x = np.zeros((int(padded.sum()) + spare_tiles * ROW_TILE, K), np.float32)
+    start = 0
+    for n, p in zip(sizes, padded):
+        x[start:start + n] = rng.randn(n, K)
+        start += p
+    return jnp.asarray(x).astype(dtype), jnp.asarray(padded, jnp.int32)
+
+
+@pytest.mark.parametrize("sizes", [(130, 0, 5, 256), (0, 0, 0), (128, 1, 300)],
+                         ids=["empty-group", "all-empty", "ragged"])
+@pytest.mark.parametrize("K,N", [(256, 384), (128, 128)])
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_ragged_grouped_matmul_kernels(sizes, K, N, dtype):
+    """``_gmm_ragged_kernel`` (forward, and dX with w transposed) and
+    ``_tgmm_ragged_kernel`` (dW) in interpret mode, through the custom VJP,
+    against ``jax.lax.ragged_dot`` and its VJP on the same padded layout:
+    rows past the groups and a group with no rows come out zero."""
+    x, gs = _ragged_rows(sizes, K, dtype)
+    G = len(sizes)
+    w = jnp.asarray(rng.randn(G, K, N) * 0.1, jnp.float32).astype(dtype)
+    dy = jnp.asarray(rng.randn(x.shape[0], N), jnp.float32).astype(dtype)
+    got, vjp = jax.vjp(lambda x, w: ops.ragged_grouped_matmul(x, w, gs, interpret=True), x, w)
+    want, vjp_ref = jax.vjp(lambda x, w: ref.ragged_grouped_matmul(x, w, gs), x, w)
+    # relative to each result's largest entry: both sides accumulate in f32
+    # from the same operands; bf16 results round to 2**-8
+    atol = 1e-4 if dtype == np.float32 else 1e-2
+    for a, b in zip((got, *vjp(dy)), (want, *vjp_ref(dy))):
+        assert a.dtype == b.dtype
+        scale = max(float(jnp.max(jnp.abs(b.astype(jnp.float32)))), 1.0)
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32),
+                                   atol=atol * scale)
+    ends = np.cumsum(np.asarray(gs))
+    assert not np.asarray(got[ends[-1]:], np.float32).any()
+    dw = vjp(dy)[1]
+    for g, n in enumerate(sizes):
+        if n == 0:
+            assert not np.asarray(dw[g], np.float32).any()
+
+
+def test_ragged_tile_metadata():
+    """Each row tile's group, and the dW kernel's steps: every tile of a
+    group in order (first and last flagged), one step for an empty group,
+    and steps past the last real one with no flag."""
+    from repro.kernels import grouped_matmul as gm
+    gs = jnp.asarray([256, 0, 128], jnp.int32)
+    group, n_live = gm.tile_groups(gs, 5, tm=128)
+    assert list(np.asarray(group)) == [0, 0, 2, 2, 2] and int(n_live[0]) == 3
+    group, tile, flags = gm.tile_items(gs, 5, tm=128)
+    assert list(np.asarray(group)) == [0, 0, 1, 2, 2, 2, 2, 2]
+    assert list(np.asarray(tile)[[0, 1, 3]]) == [0, 1, 2]
+    F, L, V = gm.FIRST, gm.LAST, gm.LIVE
+    assert list(np.asarray(flags)) == [F | V, L | V, F | L, F | L | V, 0, 0, 0, 0]
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_flash_attention_mla_widths(dtype):
+    """q and k 192 wide, v 128 (multi-head latent attention): the Pallas
+    forward and both backward kernels, in interpret mode through the TPU
+    attention entry, against ``chunked_attention`` and its VJP."""
+    from repro.models.attention import chunked_attention
+    B, S, H, dqk, dv = 1, 256, 2, 192, 128
+    q, k = (jnp.asarray(rng.randn(B, S, H, dqk) * 0.5, jnp.float32).astype(dtype)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(B, S, H, dv) * 0.5, jnp.float32).astype(dtype)
+    do = jnp.asarray(rng.randn(B, S, H, dv), jnp.float32).astype(dtype)
+    out, vjp = jax.vjp(lambda q, k, v: ops.flash_attention(q, k, v, interpret=True), q, k, v)
+    assert out.shape == (B, S, H, dv)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
+    want, vjp_ref = jax.vjp(lambda q, k, v: chunked_attention(q, k, v, chunk=128), *f32[:3])
+    _assert_rel_close((out,), (want,), BWD_RTOL[dtype])
+    got = vjp(do)
+    assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    _assert_rel_close(got, vjp_ref(f32[3]), BWD_RTOL[dtype])

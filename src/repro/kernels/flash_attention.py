@@ -23,19 +23,32 @@ recomputes ``P = exp(s - lse)`` from q and k (``s = q k^T * scale``) and
 - ``_flash_bwd_dq_kernel``: grid (B, Hq, Sq/bq, Sk/bk), sequential over kv
   blocks, accumulating ``dQ += dS k * scale``.
 
-A block pair wholly masked (above the causal diagonal, outside the window,
-past ``k_len``) is neither fetched nor computed: the index_maps clamp the
-sequential axis to the live range, so the block index repeats and no DMA is
-issued, and the compute is under ``pl.when``.  Iota masks are built only on
-the blocks the diagonal, the window edge or ``k_len`` crosses.  The
-backward's block sizes come from the shapes (:func:`bwd_blocks`).
+All three kernels share one schedule.  A block pair wholly masked (above
+the causal diagonal, outside the window, past ``k_len``) is neither fetched
+nor computed: the index_maps clamp the sequential axis to the live range
+(:func:`_k_blk`, :func:`_q_range`), so a dead step repeats its neighbour's
+block index and no DMA is issued, and the compute is under ``pl.when``.
+The forward still writes its output on the last k-step, live or not.  Iota
+masks are built only on the blocks the diagonal, the window edge or
+``k_len`` crosses (:func:`_when_live`).
+
+Blocks come from the shapes: ops.py pads every length to a multiple of 128
+only, and each kernel takes the largest of its block sizes that divides the
+padded length (:func:`fwd_blocks`, :func:`bwd_blocks`), so a length 512
+does not divide gets a smaller block, not more padding.  The forward takes
+up to 1024 x 1024, smaller where the head widths and dtype would overfill
+the scoped VMEM.  ``bq``/``bk`` override them (tests, sweeps).
+
+Heads narrower than the 128-wide MXU cost: the softmax's elementwise work
+is per score, the products' per score and head column, so at equal FLOPs
+the forward took 1.87x as long with heads of 64 as with heads of 128 (TPU
+v5e, seq 2048, ``benchmarks/attention_fwd_sweep.py``).
 
 Precision: MXU operands in the inputs' dtype (P and dS are cast to it),
 f32 accumulation; softmax statistics, D and dS's elementwise math in f32.
-
-MXU alignment: bq/bk default 128 (q is padded by ops.py when Sq < bq);
-head_dim should be a multiple of 128 for full MXU utilization — smaller
-head dims still compile but underfill the systolic array.
+With bf16 inputs the forward scales ``s`` after the product (bf16 products
+are exact in f32; ``scale`` is not a power of two at every head width);
+with f32 inputs it scales q, as the jnp attention does.
 
 Masking supports causal, bidirectional and sliding-window.
 """
@@ -61,6 +74,13 @@ BWD_DQ_KERNEL_NAME = "_flash_bwd_dq_kernel"
 # length is taken.  512 beat 256 and 1024 for both kernels at seq 2048, head
 # 64 on a TPU v5e (``benchmarks/attention_bwd_sweep.py``).
 BWD_BLOCKS = (512, 256, 128)
+# Forward block sizes, largest first.  1024 x 1024 was the fastest pair at
+# every benchmark shape (seq 2048, heads of 64; seq 8192, q and k 192, v 128)
+# on a TPU v5e (``benchmarks/attention_fwd_sweep.py``): each grid step has a
+# fixed cost, and larger blocks take fewer steps.
+FWD_BLOCKS = (1024, 512, 256, 128)
+# What the forward's blocks may hold in VMEM: a TPU v5e's default scoped VMEM.
+FWD_VMEM_BYTES = 16 * 2**20
 
 
 def _col_to_row(x):
@@ -84,107 +104,28 @@ def _valid(q_pos, k_pos, *, kind, window, k_len):
     return valid
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                  *, scale: float, kind: str, window: int, bq: int, bk: int,
-                  k_len: int):
-    ki = pl.program_id(3)
-    qi = pl.program_id(2)
-    n_k = pl.num_programs(3)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    valid = _valid(q_pos, k_pos, kind=kind, window=window, k_len=k_len)
-
-    # block-level skip: causal blocks entirely above the diagonal do no work
-    block_live = True
-    if kind == "causal":
-        block_live = (qi + 1) * bq - 1 >= ki * bk
-
-    @pl.when(block_live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale           # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)                   # (bk, d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(ki == n_k - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = _col_to_row(m_scr[...] + jnp.log(l))
+def _fwd_vmem(bq, bk, d, dv, itemsize):
+    """Bytes the forward keeps in VMEM at blocks (bq, bk): the q, k, v and o
+    blocks double-buffered, the f32 accumulator and two f32 (bq, bk) score
+    temporaries.  Above the compiler's count: a v5e compiles the forward at
+    16.75 MiB by it (bf16, heads of 448) and refuses it at 17 MiB."""
+    return 2 * (bq + bk) * (d + dv) * itemsize + 4 * bq * dv + 8 * bq * bk
 
 
-def flash_attention_fwd(q, k, v, *, kind: str = "causal", window: int = 0,
-                        k_len: int | None = None, scale: float | None = None,
-                        bq: int = 128, bk: int = 128,
-                        interpret: bool = False):
-    """q, k: (B, Hq, Sq, d), (B, Hkv, Sk, d);  v: (B, Hkv, Sk, dv) ->
-    (o (B, Hq, Sq, dv), lse (B, Hq, 1, Sq) f32).
+def fwd_blocks(sq: int, sk: int, d: int, dv: int, itemsize: int):
+    """The forward's (bq, bk) for padded lengths ``sq``, ``sk`` (multiples
+    of 128) and head widths ``d`` (q, k) and ``dv`` (v): under each cap in
+    ``FWD_BLOCKS``, largest first, the largest block of ``FWD_BLOCKS`` that
+    divides each length; the first pair that fits ``FWD_VMEM_BYTES``."""
+    def under(n, cap):
+        return next((b for b in FWD_BLOCKS if b <= cap and n % b == 0), n)
 
-    Shapes must be block-aligned (ops.py pads); GQA via Hq = g * Hkv.
-    """
-    B, Hq, Sq, d = q.shape
-    _, Hkv, Sk, _ = k.shape
-    dv = v.shape[-1]
-    g = Hq // Hkv
-    scale = scale if scale is not None else d ** -0.5
-    bq = min(bq, Sq)
-    bk = min(bk, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0, (Sq, bq, Sk, bk)
-    grid = (B, Hq, Sq // bq, Sk // bk)
+    for cap in FWD_BLOCKS:
+        bq, bk = under(sq, cap), under(sk, cap)
+        if _fwd_vmem(bq, bk, d, dv, itemsize) <= FWD_VMEM_BYTES:
+            break
+    return bq, bk
 
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, kind=kind, window=window, bq=bq, bk=bk,
-        k_len=Sk if k_len is None else k_len)
-
-    from jax.experimental.pallas import tpu as pltpu
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j, g=g: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, dv), lambda b, h, i, j, g=g: (b, h // g, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, dv), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((B, Hq, Sq, dv), q.dtype),
-                   jax.ShapeDtypeStruct((B, Hq, 1, Sq), jnp.float32)],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, dv), jnp.float32),
-        ],
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-        name=KERNEL_NAME,
-    )(q, k, v)
-
-
-# ---------------------------------------------------------------------------
-# backward
-# ---------------------------------------------------------------------------
 
 def bwd_blocks(n: int) -> int:
     """The backward's block along an axis of padded length ``n`` (a multiple
@@ -228,6 +169,13 @@ def _k_range(qi, *, kind, window, k_len, bq, bk, n_k):
     return jnp.minimum(lo, hi), hi
 
 
+def _k_blk(qi, kj, **st):
+    """The kv block fetched at sequential step ``kj`` of q block ``qi``:
+    ``kj`` clamped to the live range, so a dead step repeats its neighbour's
+    block index and issues no DMA."""
+    return jnp.clip(kj, *_k_range(qi, **st))
+
+
 def _when_live(live, whole, step):
     """Run ``step(masked)`` on a live block pair, with the iota mask only
     where the pair is not wholly unmasked."""
@@ -238,6 +186,114 @@ def _when_live(live, whole, step):
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _NN = (((1,), (0,)), ((), ()))      # a @ b
 
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                  *, scale: float, kind: str, window: int, bq: int, bk: int,
+                  k_len: int):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def step(masked):
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
+        if q.dtype == jnp.float32:   # scale on q, as the jnp attention rounds
+            s = jax.lax.dot_general(q * scale, k, _NT,
+                                    preferred_element_type=jnp.float32)
+        else:   # narrower products are exact in f32: scale s, not q
+            s = jax.lax.dot_general(q, k, _NT,
+                                    preferred_element_type=jnp.float32) * scale
+        if masked:
+            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            s = jnp.where(_valid(q_pos, k_pos, kind=kind, window=window,
+                                 k_len=k_len), s, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    _when_live(*_pair(qi, ki, kind=kind, window=window, k_len=k_len, bq=bq,
+                      bk=bk), step)
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finish():
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = _col_to_row(m_scr[...] + jnp.log(l))
+
+
+def flash_attention_fwd(q, k, v, *, kind: str = "causal", window: int = 0,
+                        k_len: int | None = None, scale: float | None = None,
+                        bq: int | None = None, bk: int | None = None,
+                        interpret: bool = False):
+    """q, k: (B, Hq, Sq, d), (B, Hkv, Sk, d);  v: (B, Hkv, Sk, dv) ->
+    (o (B, Hq, Sq, dv), lse (B, Hq, 1, Sq) f32).
+
+    Shapes must be block-aligned (ops.py pads); GQA via Hq = g * Hkv.
+    bq/bk default to :func:`fwd_blocks` of the shapes.
+    """
+    B, Hq, Sq, d = q.shape
+    _, Hkv, Sk, _ = k.shape
+    dv = v.shape[-1]
+    g = Hq // Hkv
+    auto = fwd_blocks(Sq, Sk, d, dv, q.dtype.itemsize)
+    bq, bk = min(bq or auto[0], Sq), min(bk or auto[1], Sk)
+    assert Sq % bq == 0 and Sk % bk == 0, (Sq, bq, Sk, bk)
+    k_len = Sk if k_len is None else k_len
+    n_k = Sk // bk
+
+    def kv_map(b, h, i, j):
+        return (b, h // g, _k_blk(i, j, kind=kind, window=window, k_len=k_len,
+                                  bq=bq, bk=bk, n_k=n_k), 0)
+
+    def q_map(b, h, i, j):
+        return (b, h, i, 0)
+
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        functools.partial(
+            _flash_kernel, scale=scale if scale is not None else d ** -0.5,
+            kind=kind, window=window, bq=bq, bk=bk, k_len=k_len),
+        grid=(B, Hq, Sq // bq, n_k),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), q_map),
+            pl.BlockSpec((1, 1, bk, d), kv_map),
+            pl.BlockSpec((1, 1, bk, dv), kv_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, bq, dv), q_map),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((B, Hq, Sq, dv), q.dtype),
+                   jax.ShapeDtypeStruct((B, Hq, 1, Sq), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
+        ],
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# backward
+# ---------------------------------------------------------------------------
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
@@ -386,16 +442,13 @@ def flash_bwd_dq(q, k, v, do, lse, di, *, kind: str = "causal",
     bq, bk = st["bq"], st["bk"]
     n_k = Sk // bk
 
-    def k_blk(i, j):
-        return jnp.clip(j, *_k_range(i, kind=kind, window=window,
-                                     k_len=st["k_len"], bq=bq, bk=bk, n_k=n_k))
-
     from jax.experimental.pallas import tpu as pltpu
     def q_map(b, h, i, j):
         return (b, h, i, 0)
 
     def kv_map(b, h, i, j):
-        return (b, h // g, k_blk(i, j), 0)
+        return (b, h // g, _k_blk(i, j, kind=kind, window=window,
+                                  k_len=st["k_len"], bq=bq, bk=bk, n_k=n_k), 0)
 
     q_spec = pl.BlockSpec((1, 1, bq, d), q_map)
     row_spec = pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i))

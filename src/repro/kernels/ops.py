@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import compat, tacc
 from repro.kernels import ref
 from repro.kernels.collective_reduce import collective_reduce as _cr_pallas
-from repro.kernels.flash_attention import (flash_attention_bwd,
+from repro.kernels.flash_attention import (LANES, flash_attention_bwd,
                                            flash_attention_fwd)
 from repro.kernels.grouped_matmul import grouped_matmul as _gmm_pallas
 from repro.kernels.grouped_matmul import gmm_ragged, tgmm_ragged
@@ -42,12 +42,13 @@ def _pad_to(x, multiple: int, axis: int):
 
 def flash_attention(q, k, v, *, kind="causal", window=0, q_offset=0,
                     k_offset=0, k_len=None, chunk=None, scale=None,
-                    interpret=False, bq=128, bk=128):
+                    interpret=False, bq=None, bk=None):
     """Model-layout wrapper for the Pallas flash kernels.
 
     Decode (Sq < 8) and offset cases fall back to the chunked-jnp path —
     the kernels target the big training/prefill shapes.  Differentiable:
-    see :func:`_flash` for the backward.
+    see :func:`_flash` for the backward.  The forward's blocks ``bq``/``bk``
+    come from the shapes (``fwd_blocks``) unless given.
     """
     from repro.models.attention import chunked_attention
     B, Sq, Hq, d = q.shape
@@ -65,15 +66,16 @@ class _FlashCfg(NamedTuple):
     window: int
     k_len: int              # valid kv prefix
     scale: float | None
-    bq: int                 # the forward's blocks
-    bk: int
+    bq: int | None          # the forward's blocks; None: from the shapes
+    bk: int | None
     interpret: bool
     sk: int                 # kv length before padding
 
 
-def _to_kernel(x, block):
-    """(B, S, H, d) -> (B, H, S, d), S padded to a multiple of ``block``."""
-    return _pad_to(jnp.moveaxis(x, 1, 2), block, 2)[0]
+def _to_kernel(x, block=None):
+    """(B, S, H, d) -> (B, H, S, d), S padded to a multiple of ``block``
+    (of 128 when None: the kernels' blocks divide it)."""
+    return _pad_to(jnp.moveaxis(x, 1, 2), block or LANES, 2)[0]
 
 
 def _from_kernel(x, s):

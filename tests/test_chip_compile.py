@@ -66,6 +66,14 @@ def test_flash_attention_forward_compiles(one_chip):
     assert "tpu_custom_call" in _hlo(ops.flash_attention, q, kv, kv)
 
 
+@pytest.mark.parametrize("dtype,d", [(jnp.float32, 256), (jnp.bfloat16, 512)])
+def test_flash_attention_forward_fits_vmem_at_wide_heads(one_chip, dtype, d):
+    """Where 1024 x 1024 blocks would overfill the scoped VMEM (f32 heads of
+    256, bf16 heads of 512), the blocks the shapes choose still compile."""
+    q = _sds((1, S, 2, d), dtype, one_chip)
+    assert "tpu_custom_call" in _hlo(ops.flash_attention, q, q, q)
+
+
 # Moonlight-16B-A3B's latent attention at seq 8192, micro-batch 2: q, k 192
 # wide, v 128 (configs/moonshot_v1_16b_a3b.py)
 MLA = (2, 8192, 16, 16, 192, 128)

@@ -194,6 +194,98 @@ def test_flash_attention_bwd_live_ranges(kind, window, k_len, bq, bk):
                 assert not q_lo <= i <= q_hi and not k_lo <= j <= k_hi, (i, j)
 
 
+@pytest.mark.parametrize("kind,window,k_len", [
+    ("causal", 0, None), ("bidir", 0, None), ("causal", 300, None),
+    ("bidir", 0, 700), ("causal", 0, 600)],
+    ids=["causal", "bidir", "window", "k_len", "causal-k_len"])
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_flash_attention_fwd_at_chosen_blocks(kind, window, k_len, dtype):
+    """The forward at the blocks the shapes choose (``fwd_blocks``), with
+    GQA and v narrower than q and k, matches the dense oracle; f32 inputs
+    at the f32 tolerance the 128-wide blocks are held to."""
+    from repro.kernels import flash_attention as fa
+    B, Hq, Hkv, S, d, dv = 1, 4, 2, 1024, 64, 32
+    assert fa.fwd_blocks(S, S, d, dv, jnp.dtype(dtype).itemsize) == (S, S)
+    q = jnp.asarray(rng.randn(B, Hq, S, d) * 0.5, jnp.float32).astype(dtype)
+    k = jnp.asarray(rng.randn(B, Hkv, S, d) * 0.5, jnp.float32).astype(dtype)
+    v = jnp.asarray(rng.randn(B, Hkv, S, dv) * 0.5, jnp.float32).astype(dtype)
+    kw = dict(kind=kind, window=window, k_len=k_len)
+    got, lse = flash_attention_fwd(q, k, v, interpret=True, **kw)
+    want = ref.attention(q, k, v, **kw)
+    assert got.shape == (B, Hq, S, dv) and lse.shape == (B, Hq, 1, S)
+    atol = 3e-4 if dtype == np.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol)
+
+
+@pytest.mark.parametrize("n,block", [(128, 128), (256, 256), (640, 128),
+                                     (768, 256), (1152, 128), (1536, 512),
+                                     (2048, 1024), (8192, 1024)])
+def test_flash_attention_fwd_blocks_divide_the_padded_length(n, block):
+    """The forward's block divides the 128-padded length, and lengths 512
+    does not divide take a smaller divisor, not more padding."""
+    from repro.kernels import flash_attention as fa
+    assert fa.fwd_blocks(n, n, 64, 64, 2) == (block, block)
+    assert fa.fwd_blocks(n, 2048, 192, 128, 2) == (block, 1024)
+
+
+@pytest.mark.parametrize("d,dv,itemsize,blocks", [
+    (64, 64, 2, (1024, 1024)), (192, 128, 2, (1024, 1024)),
+    (192, 128, 4, (1024, 1024)), (256, 256, 2, (1024, 1024)),
+    (256, 256, 4, (512, 512)), (512, 512, 2, (512, 512))])
+def test_flash_attention_fwd_blocks_fit_scoped_vmem(d, dv, itemsize, blocks):
+    """Wide heads and f32 inputs step the forward's blocks down until they
+    fit the scoped VMEM (``tests/test_chip_compile.py`` compiles the ones
+    that step down for a v5e)."""
+    from repro.kernels import flash_attention as fa
+    assert fa.fwd_blocks(2048, 2048, d, dv, itemsize) == blocks
+    assert fa._fwd_vmem(*blocks, d, dv, itemsize) <= fa.FWD_VMEM_BYTES
+
+
+@pytest.mark.parametrize("S", [600, 1100], ids=["640", "1152"])
+def test_flash_attention_padded_residuals_stay_128_aligned(S):
+    """Through the model-layout entry at lengths 512 does not divide, the
+    backward's residuals are padded to a multiple of 128 only, and the
+    forward's value and gradients match the oracle's."""
+    from repro.models.attention import dense_reference
+    B, Hq, Hkv, d = 1, 2, 1, 32
+    q = jnp.asarray(rng.randn(B, S, Hq, d) * 0.5, jnp.float32)
+    k, v = (jnp.asarray(rng.randn(B, S, Hkv, d) * 0.5, jnp.float32)
+            for _ in range(2))
+    w = jnp.asarray(rng.randn(B, S, Hq, d), jnp.float32)
+    cfg = ops._FlashCfg("causal", 0, S, None, None, None, True, S)
+    _, res = ops._flash_manual(q, k, v, cfg, residuals=True)
+    padded = -(-S // 128) * 128
+    assert [r.shape[2] for r in res[:4]] == [padded] * 4
+    assert res[4].shape == (B, Hq, 1, padded)
+    got = jax.jit(lambda *a: _attention_grads(
+        lambda q, k, v: ops.flash_attention(q, k, v, interpret=True),
+        *a))(q, k, v, w)
+    want = _attention_grads(dense_reference, q, k, v, w)
+    _assert_grads_close(got, want, atol=1e-3)
+
+
+@pytest.mark.parametrize("kind,window,k_len", [
+    ("causal", 0, 2048), ("causal", 300, 2048), ("causal", 700, 1500),
+    ("bidir", 0, 2048), ("bidir", 0, 900)])
+@pytest.mark.parametrize("bq,bk", [(512, 512), (256, 512), (512, 128),
+                                   (1024, 256)])
+def test_flash_attention_fwd_clamped_index_map(kind, window, k_len, bq, bk):
+    """The forward's kv index map (``_k_blk``) keeps every live step's own
+    block and sends every dead step to a live block of the same q block,
+    so a dead step issues no fetch of its own."""
+    from repro.kernels import flash_attention as fa
+    S = 2048
+    n_q, n_k = S // bq, S // bk
+    st = dict(kind=kind, window=window, k_len=k_len, bq=bq, bk=bk)
+    for i in range(n_q):
+        for j in range(n_k):
+            jj = int(fa._k_blk(i, j, n_k=n_k, **st))
+            assert bool(fa._pair(i, jj, **st)[0]), (i, j, jj)
+            if bool(fa._pair(i, j, **st)[0]):
+                assert jj == j, (i, j, jj)
+
+
 @pytest.mark.parametrize("G,M,K,N", [(4, 200, 96, 160), (1, 128, 128, 128),
                                      (8, 64, 300, 48)])
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
